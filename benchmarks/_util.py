@@ -3,12 +3,12 @@ subprocess fan-out over device counts, CSV emission (format:
 name,us_per_call,derived)."""
 from __future__ import annotations
 
-import json
 import os
+import re
 import subprocess
 import sys
 import time
-from typing import Any, NamedTuple
+from typing import NamedTuple
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -56,19 +56,40 @@ def emit(name: str, us_per_call: float, derived=""):
     print(f"{name},{us_per_call:.1f},{derived}", flush=True)
 
 
+_HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count"
+
+
 def run_sub(module: str, devices: int, *args, timeout=560):
-    """Run a benchmark module in a subprocess with N host devices; returns its
-    stdout (the module prints CSV lines)."""
+    """Run a benchmark module in a subprocess with N host (CPU) devices;
+    returns its stdout (the module prints CSV lines). A child that fails
+    ends this process with its exit code: no partial table is printed as
+    if it were complete."""
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["XLA_FLAGS"] = f"{_HOST_DEVICES_FLAG}={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + ROOT
     cmd = [sys.executable, "-m", module] + [str(a) for a in args]
     proc = subprocess.run(cmd, capture_output=True, text=True,
                           timeout=timeout, env=env, cwd=ROOT)
     if proc.returncode != 0:
         sys.stderr.write(proc.stderr[-2000:])
-        return f"{module},-1,ERROR\n"
+        sys.exit(f"{module} failed with exit code {proc.returncode}")
     return proc.stdout
+
+
+def num_ranks() -> int:
+    """The rank count a brain bench runs on: ``len(jax.devices())``. Where
+    ``XLA_FLAGS`` asks for N host devices, exactly N must exist — the flag
+    only makes CPU devices, and on an accelerator host it is ignored, so a
+    "4-rank" request would otherwise run on however many chips there are."""
+    import jax
+    r = len(jax.devices())
+    m = re.search(rf"{_HOST_DEVICES_FLAG}=(\d+)",
+                  os.environ.get("XLA_FLAGS", ""))
+    if m and int(m.group(1)) != r:
+        raise RuntimeError(
+            f"asked for {m.group(1)} ranks through {_HOST_DEVICES_FLAG}, but "
+            f"jax sees {r} {jax.default_backend()} device(s)")
+    return r
 
 
 # paper record sizes (bytes) for Table I/II accounting

@@ -27,7 +27,7 @@ import sys
 import jax
 
 from benchmarks._util import ROOT, emit, measure
-from repro import compat, telemetry
+from repro import telemetry
 from repro.configs.msp_brain import BrainConfig
 from repro.core import engine
 from repro.kernels.activity_fused import window_hbm_bytes
@@ -48,8 +48,8 @@ def make_activity_fn(cfg, mesh):
                                       "ranks", num_ranks)
         return sim_phases.activity_phase(st, ctx)
 
-    return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(specs,),
-                                    out_specs=specs, check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                 out_specs=specs, check_vma=False))
 
 
 def main():

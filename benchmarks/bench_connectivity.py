@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks._util import ROOT, emit, measure
-from repro import compat, telemetry
+from repro import telemetry
 from repro.configs.msp_brain import BrainConfig
 from repro.connectome import routing, traverse
 from repro.connectome import tree as ctree
@@ -90,8 +90,8 @@ def make_conn_fn(cfg, mesh):
                                       "ranks", num_ranks)
         return sim_phases.connectivity_phase(st, ctx)
 
-    return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(specs,),
-                                    out_specs=specs, check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                 out_specs=specs, check_vma=False))
 
 
 def phase_b_reference_bytes(cfg, st, num_ranks):
@@ -230,8 +230,8 @@ def make_exchange_fn(cfg, mesh):
             (b1.sum() + b2.sum()).astype(jnp.float32)
         return jnp.reshape(s, (1,))
 
-    return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(specs,),
-                                    out_specs=P("ranks"), check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                 out_specs=P("ranks"), check_vma=False))
 
 
 def exchange_hbm_bytes(cfg, num_ranks, q):

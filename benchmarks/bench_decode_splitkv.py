@@ -5,7 +5,7 @@ import jax.numpy as jnp
 
 from benchmarks._util import emit, time_fn
 from repro.launch import roofline as rl
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 
 def main():

@@ -10,7 +10,7 @@ baseline the regression gate compares against).
 import os
 import sys
 
-from benchmarks._util import ROOT, brain_sim_timed, emit
+from benchmarks._util import ROOT, brain_sim_timed, emit, num_ranks
 
 
 def main():
@@ -20,7 +20,7 @@ def main():
     n = int(args[0]) if args else (64 if smoke else 512)
     import jax
     from repro import telemetry
-    r = len(jax.devices())
+    r = num_ranks()
     levels, frontier, s_max = (3, 32, 8) if smoke else (4, 64, 32)
     metrics, sims = {}, {}
     for conn, spike, tag in (("old", "old", "old"), ("new", "new", "new")):

@@ -7,13 +7,12 @@ subprocesses with varying host-device counts; directly runnable too:
 """
 import sys
 
-from benchmarks._util import brain_sim, emit
+from benchmarks._util import brain_sim, emit, num_ranks
 
 
 def main():
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 256
-    import jax
-    r = len(jax.devices())
+    r = num_ranks()
     times = {}
     for alg in ("old", "new"):
         # rate_period=10 so the chunk is dominated by the connectivity update;

@@ -24,15 +24,15 @@ steady-state per-chunk time are reported separately.
 import os
 import sys
 
-from benchmarks._util import PAPER_BYTES, ROOT, brain_sim_timed, emit
+from benchmarks._util import (PAPER_BYTES, ROOT, brain_sim_timed, emit,
+                              num_ranks)
 
 
 def bench(n, chunks=2):
-    import jax
     import numpy as np
     from repro import telemetry
     from repro.core.spikes import NO_SUB
-    r = len(jax.devices())
+    r = num_ranks()
     base = dict(neurons_per_rank=n, local_levels=3, frontier_cap=32,
                 max_synapses=16, connectivity_alg="new", rate_period=100,
                 requests_cap_factor=max(r, 4), subs_cap_factor=max(r, 4))
@@ -92,7 +92,7 @@ def bench_connectome(n, chunks=2):
     from repro.core.spikes import NO_SUB
     from repro.sim import Simulator
     from repro.workloads import datasets as wds
-    r = len(jax.devices())
+    r = num_ranks()
     base = dict(neurons_per_rank=n, local_levels=3, frontier_cap=32,
                 max_synapses=16, connectivity_alg="new", rate_period=100,
                 requests_cap_factor=max(r, 4), subs_cap_factor=max(r, 4))
@@ -151,7 +151,7 @@ def main():
     n = int(args[0]) if args else (64 if smoke else 256)
     import jax
     from repro import telemetry
-    r = len(jax.devices())
+    r = num_ranks()
     if "--connectome" in sys.argv:
         bench_connectome(n)
         return

@@ -6,18 +6,17 @@ import sys
 
 import numpy as np
 
-from benchmarks._util import emit
+from benchmarks._util import emit, num_ranks
 
 
 def main():
     full = "--full" in sys.argv
     chunks = 2000 if full else 600
     import dataclasses
-    import jax
     from repro.configs.msp_brain import BrainConfig
     from repro.sim import Simulator
 
-    ndev = len(jax.devices())
+    ndev = num_ranks()
     # paper: 32 neurons SPREAD ACROSS RANKS (one per rank at 32 ranks) so the
     # rate approximation is fully exercised; here 32 total over ndev ranks
     base = BrainConfig(neurons_per_rank=max(32 // ndev, 1), local_levels=3,

@@ -15,7 +15,7 @@ import sys
 import tempfile
 import time
 
-from benchmarks._util import ROOT, emit
+from benchmarks._util import ROOT, emit, num_ranks
 
 
 def _timed_ms(fn, iters=3):
@@ -37,7 +37,7 @@ def main():
     from repro.runtime import chaos
     from repro.runtime.sim_runner import SimRunnerConfig, SimulationRunner
 
-    r = len(jax.devices())
+    r = num_ranks()
     cfg = BrainConfig(neurons_per_rank=n, local_levels=3, frontier_cap=32,
                       max_synapses=8, rate_period=10,
                       requests_cap_factor=100, subs_cap_factor=100)

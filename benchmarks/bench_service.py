@@ -29,7 +29,7 @@ import time
 
 import numpy as np
 
-from benchmarks._util import ROOT, emit
+from benchmarks._util import ROOT, emit, num_ranks
 
 
 def _drive(svc, handles):
@@ -113,7 +113,7 @@ def main():
     from repro.configs.msp_brain import BrainConfig
     from repro.service import SlotBatch
 
-    r = len(jax.devices())
+    r = num_ranks()
     # smoke-scale cases always run (the committed baseline carries them
     # too, so the gate pairs by exact name at matched params); the full
     # run adds a larger-n case for the record

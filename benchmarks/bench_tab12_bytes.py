@@ -3,13 +3,13 @@ paper's record sizes (17/42/9 B requests, 8 B spike IDs, 4 B rates, tree-node
 downloads) counted from simulation event counters."""
 import sys
 
-from benchmarks._util import brain_sim, emit, paper_bytes_from_stats
+from benchmarks._util import (brain_sim, emit, num_ranks,
+                              paper_bytes_from_stats)
 
 
 def main():
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 256
-    import jax
-    r = len(jax.devices())
+    r = num_ranks()
     out = {}
     for conn, spike in (("old", "old"), ("new", "new")):
         dt, st = brain_sim(dict(

@@ -25,18 +25,17 @@ import sys
 import time
 
 
-from benchmarks._util import ROOT, emit
+from benchmarks._util import ROOT, emit, num_ranks
 
 
 def bench(n):
-    import jax
     from repro import telemetry
     from repro.configs.msp_brain import SMOKE_CONFIG
     from repro.workloads import assimilate as was
     from repro.workloads import datasets as wds
     from repro.workloads import engram as weng
 
-    r = len(jax.devices())
+    r = num_ranks()
     cfg = dataclasses.replace(SMOKE_CONFIG, neurons_per_rank=n,
                               requests_cap_factor=1000)
     spec = weng.EngramSpec()
@@ -93,7 +92,7 @@ def main():
     n = int(args[0]) if args else 64
     import jax
     from repro import telemetry
-    r = len(jax.devices())
+    r = num_ranks()
     cases, device_metrics = bench(n)
     if write_json:
         out = "BENCH_workloads_smoke.json" if smoke \

@@ -16,9 +16,9 @@ import dataclasses, time, sys
 import jax
 from repro.configs.msp_brain import BrainConfig
 from repro.sim import Simulator
-from benchmarks._util import paper_bytes_from_stats
+from benchmarks._util import num_ranks, paper_bytes_from_stats
 
-r = len(jax.devices())
+r = num_ranks()
 for conn, spike in (("old", "old"), ("new", "new")):
     cfg = BrainConfig(neurons_per_rank=256, local_levels=3, frontier_cap=32,
                       max_synapses=16, connectivity_alg=conn, spike_alg=spike,
@@ -48,6 +48,8 @@ def main():
         sys.stdout.write(out.stdout)
         if out.returncode != 0:
             sys.stderr.write(out.stderr[-800:])
+            sys.exit(f"{devices}-rank run failed with exit code "
+                     f"{out.returncode}")
 
 
 if __name__ == "__main__":

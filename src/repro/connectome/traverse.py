@@ -47,7 +47,8 @@ MEMBER_ROUND = chash.BH_ROUNDS - 1
 
 class StackedTree(NamedTuple):
     """Uniform view of consecutive octree levels for traced indexing.
-    counts: (L, C_max); centroids: (L, C_max, 3); sizes: STATIC tuple of L
+    counts: (L, C_max); centroids: (L, C_max, 3) cell centroids (mean
+    position, zero for empty cells); sizes: STATIC tuple of L
     cell edge lengths (compile-time floats, so the Pallas kernel body closes
     over them instead of capturing a constant array).
     Level k covers absolute octree level (start_level + k); cell indices are
@@ -59,12 +60,17 @@ class StackedTree(NamedTuple):
 
 
 def stack_levels(counts_tuple, cents_tuple, start_level: int) -> StackedTree:
+    """Stack the tree's levels; ``cents_tuple`` holds per-cell position
+    SUMS, divided by the counts here, once per cell. Dividing after the
+    per-query gather instead would materialise a (Q, F, 3) quotient, whose
+    3-wide minor axis the TPU pads to 128 lanes (8 GB at 262,144 queries)."""
     lmax = max(c.shape[0] for c in counts_tuple)
     cs, zs = [], []
     for c, z in zip(counts_tuple, cents_tuple):
         pad = lmax - c.shape[0]
         cs.append(jnp.pad(c, (0, pad)))
-        zs.append(jnp.pad(z, ((0, pad), (0, 0))))
+        zs.append(jnp.pad(z / jnp.maximum(c, 1e-9)[:, None],
+                          ((0, pad), (0, 0))))
     sizes = level_sizes(len(counts_tuple), start_level)
     return StackedTree(jnp.stack(cs), jnp.stack(zs), sizes, start_level)
 
@@ -112,9 +118,7 @@ def _node_stats(tree: StackedTree, lvl_rel, cell, x, sigma):
     """Vectorized gather of (count, prob-weight, size/dist) for entries.
     lvl_rel, cell: (Q, F) int; x: (Q, 3)."""
     cnt = tree.counts[lvl_rel, cell]
-    cent = tree.centroids[lvl_rel, cell]
-    center = cent / jnp.maximum(cnt, 1e-9)[..., None]
-    d2 = pairwise_d2(x, center)
+    d2 = pairwise_d2(x, tree.centroids[lvl_rel, cell])
     size = _level_size_at(tree.sizes, lvl_rel)
     crit = size / jnp.sqrt(jnp.maximum(d2, 1e-12))
     prob = cnt * _gauss(d2, sigma)
@@ -304,10 +308,11 @@ def phase_b_core(counts, cents, leaf_members, neuron_pos, vacant_d, x,
     operation is row-independent over Q, so the kernel's query blocking
     cannot change results.
 
-    counts: (L, C); cents: (L, C, 3); sizes: static tuple of per-level cell
-    edge lengths; leaf_members: (n_leaf, M); neuron_pos/vacant_d: the
-    subtree's neuron data; x/start_cell_rel/src_gid/valid_in: (Q, ...)
-    queries; chunk/gid_base: traced i32 scalars.
+    counts: (L, C); cents: (L, C, 3) centroids (``stack_levels``); sizes:
+    static tuple of per-level cell edge lengths; leaf_members: (n_leaf,
+    M); neuron_pos/vacant_d: the subtree's neuron data;
+    x/start_cell_rel/src_gid/valid_in: (Q, ...) queries; chunk/gid_base:
+    traced i32 scalars.
     Returns (target_gid (Q,), valid (Q,), depth (Q,) i32 restart rounds)."""
     tree = StackedTree(counts, cents, tuple(sizes), 0)
     leaf_cell, valid, _, depth = bh_search(

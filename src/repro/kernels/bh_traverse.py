@@ -50,11 +50,12 @@ def bh_traverse(counts, cents, members, npos, vac, x, start_cell, src_gid,
                 interpret: bool = False):
     """Phase-B search for Q queries against one subtree.
 
-    counts: (L, C) f32; cents: (L, C, 3) f32; members: (n_leaf, M) i32;
-    npos: (N, 3) f32; vac: (N,) f32; x: (Q, 3); start_cell/src_gid: (Q,)
-    i32; valid: (Q,) bool; chunk/gid_base: traced i32 scalars; sizes: static
-    per-level cell edge lengths. Returns (target_gid (Q,) i32, valid (Q,),
-    depth (Q,) i32 restart rounds — the telemetry frontier-depth signal).
+    counts: (L, C) f32; cents: (L, C, 3) f32 centroids (``stack_levels``);
+    members: (n_leaf, M) i32; npos: (N, 3) f32; vac: (N,) f32; x: (Q, 3);
+    start_cell/src_gid: (Q,) i32; valid: (Q,) bool; chunk/gid_base: traced
+    i32 scalars; sizes: static per-level cell edge lengths. Returns
+    (target_gid (Q,) i32, valid (Q,), depth (Q,) i32 restart rounds — the
+    telemetry frontier-depth signal).
 
     Q that is not a multiple of the block is padded up to it (padded rows
     carry valid=False and are sliced off — same fix as ``neuron_step``)."""

@@ -1,13 +1,13 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run with interpret=True (the kernel body is
-executed in Python per grid step — correctness only). On TPU, set
-``REPRO_PALLAS=device`` (or pass interpret=False) for the compiled path.
+On a TPU the kernels compile for the chip. On the CPU backend (tests and
+rehearsals) they run with interpret=True: the kernel body is executed per
+grid step, for correctness only. Any other backend is an error, never a
+silent fallback to the interpreter.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
@@ -23,9 +23,12 @@ from repro.kernels.synapse_apply import synapse_apply as synapse_apply_kernel
 
 
 def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS", "") == "device":
-        return False
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"no Pallas lowering for backend {backend!r}: "
+                           "kernels compile on 'tpu' and are interpreted "
+                           "on 'cpu' only")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))

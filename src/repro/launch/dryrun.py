@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from repro.configs import SHAPES, get_config, get_shape
 from repro.configs.base import applicable_shapes, supports_long_context
 from repro.launch import roofline as rl
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (make_decode_step, make_prefill_step,
                                 make_train_step, opt_config_for)
 from repro.models import build_model, decode_state_specs, input_specs
@@ -188,7 +188,8 @@ def lower_cell(arch, shape_name, multi_pod, sets=None):
         mem_bytes_dev = pbytes + 2 * sbytes
 
     terms = rl.roofline_terms(flops_dev, mem_bytes_dev,
-                              ana["collective_bytes_total"])
+                              ana["collective_bytes_total"],
+                              device_kind=rl.V5E)
     record.update(
         ok=True, lower_s=round(t_lower, 2), compile_s=round(t_compile, 2),
         memory_analysis=mem, cost_analysis=cost,
@@ -227,7 +228,8 @@ def lower_brain_cell(shape_name, multi_pod, sets=None):
     hlo = compiled.as_text()
     ana = rl.analyze_hlo(hlo, ndev)
     terms = rl.roofline_terms(ana["dot_flops"], max(ana["dot_flops"], 1.0),
-                              ana["collective_bytes_total"])
+                              ana["collective_bytes_total"],
+                              device_kind=rl.V5E)
     return {"arch": "msp-brain", "shape": shape_name, "multi_pod": multi_pod,
             "mesh": "x".join(str(s) for s in mesh.shape.values()),
             "kind": "brain", "ok": True, "overrides": sets or [],

@@ -7,8 +7,8 @@ inside the factory functions. The dry-run sets
 """
 from __future__ import annotations
 
-from repro import compat
-from repro.compat import AxisType
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,20 +16,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2x16x16 = 512 chips ('pod','data','model')."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(shape))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary test mesh, e.g. ((2,4), ('data','model')) on host devices."""
-    return compat.make_mesh(tuple(shape), tuple(axes),
-                            axis_types=(AxisType.Auto,) * len(shape))
-
-
-# TPU v5e hardware model for the roofline (targets, not the CPU runtime)
-HW = {
-    "peak_flops_bf16": 197e12,   # per chip
-    "hbm_bw": 819e9,             # bytes/s per chip
-    "ici_bw": 50e9,              # bytes/s per link (~4 links usable per chip)
-    "hbm_bytes": 16e9,
-}
+    """Arbitrary mesh, e.g. ((2,4), ('data','model')) on host devices. Every
+    axis is ``Auto``: jax's own default is ``Explicit``, under which the
+    best-effort ``with_sharding_constraint`` calls of ``parallel.sharding``
+    are rejected."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))

@@ -8,7 +8,8 @@ shapes) and derives:
     their trip counts (scan-over-layers!), and converted to *wire bytes* with
     ring-algorithm factors over the parsed replica-group size;
   * dot FLOPs (trip-count aware, so scanned layers count L times);
-  * the three roofline terms in seconds per step on TPU v5e constants.
+  * the three roofline terms in seconds per step, against the published
+    peaks of the device kind the caller names (``PEAKS``).
 
 The memory term uses ``compiled.cost_analysis()`` "bytes accessed" when the
 backend reports it, corrected for loop trip counts by the same multiplier
@@ -22,7 +23,25 @@ import re
 from collections import defaultdict
 from typing import Dict, Optional
 
-from repro.launch.mesh import HW
+# Published per-chip peaks, keyed by jax's ``device_kind``. Source: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s chip-to-chip interconnect = 4 links x 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"peak_flops_bf16": 197e12, "hbm_bw": 819e9,
+                    "ici_bw": 50e9, "hbm_bytes": 16e9},
+}
+# the target the analytic (CPU-compiled HLO) byte models are priced against
+V5E = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """The ``PEAKS`` row of one device kind; an unknown kind is an error,
+    never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -329,11 +348,14 @@ def materialized_bytes(hlo: str) -> float:
 
 
 def roofline_terms(dot_flops_per_dev: float, mem_bytes_per_dev: float,
-                   coll_bytes_per_dev: float, ici_links: float = 4.0):
-    """Three roofline terms in seconds (per device, per step)."""
-    t_compute = dot_flops_per_dev / HW["peak_flops_bf16"]
-    t_memory = mem_bytes_per_dev / HW["hbm_bw"]
-    t_coll = coll_bytes_per_dev / (HW["ici_bw"] * ici_links)
+                   coll_bytes_per_dev: float, *, device_kind: str,
+                   ici_links: float = 4.0):
+    """Three roofline terms in seconds (per device, per step) on the peaks
+    of ``device_kind``."""
+    hw = peaks(device_kind)
+    t_compute = dot_flops_per_dev / hw["peak_flops_bf16"]
+    t_memory = mem_bytes_per_dev / hw["hbm_bw"]
+    t_coll = coll_bytes_per_dev / (hw["ici_bw"] * ici_links)
     dominant = max((t_compute, "compute"), (t_memory, "memory"),
                    (t_coll, "collective"))
     return {"t_compute_s": t_compute, "t_memory_s": t_memory,

@@ -18,7 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -129,7 +128,7 @@ def attn_block_decode(p, cfg: ModelConfig, x_t, cache, pos, mesh):
                 softcap=cfg.attn_logit_softcap)
             return attn_lib.combine_partial(o, m, l, "model")
 
-        o = compat.shard_map(
+        o = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(bspec, None, None), P(bspec, None, "model", None),
                       P(bspec, None, "model", None)),

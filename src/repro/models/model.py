@@ -10,7 +10,6 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import decode as decode_lib
 from repro.models import encdec as encdec_lib
@@ -48,13 +47,9 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
     def loss(params, batch, mesh=None):
         tokens, extra = _split_batch(cfg, batch)
         n_patch = 0 if extra is None else extra.shape[1]
-        # old XLA cannot nest the vocab-parallel shard_map inside a partial
-        # manual region (Delta-periodic pod loop) — fall back to dense CE
-        nested_ok = compat.PARTIAL_MANUAL_CONSTRAINT_OK \
-            or not compat.manual_axes()
         if cfg.parallel.ce_mode == "vocab_parallel" and mesh is not None \
                 and mesh.shape.get("model", 1) > 1 \
-                and cfg.parallel.layout == "tp" and nested_ok:
+                and cfg.parallel.layout == "tp":
             hidden, aux = tfm.forward(params, cfg, tokens, extra_embeds=extra,
                                       mesh=mesh, return_hidden=True)
             h = hidden[:, n_patch:-1, :]

@@ -25,8 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.configs.base import ModelConfig
 from repro.models.layers import dtype_of, init_mlp, apply_mlp
 
@@ -150,7 +148,7 @@ def moe_move_compute(p, cfg: ModelConfig, x2d, *, model_axis="model",
     movement across the model axis."""
     t, d = x2d.shape
     e, k = cfg.num_experts, cfg.top_k
-    p_sz = compat.axis_size(model_axis)
+    p_sz = jax.lax.axis_size(model_axis)
     e_loc = e // p_sz
     assert e % p_sz == 0, (e, p_sz)
 
@@ -279,7 +277,7 @@ def apply_moe(p, cfg: ModelConfig, x, *, mesh=None, strategy=None):
                 aux_ = jax.lax.pmean(aux_, ax)
             return y_, aux_
 
-        y, aux = compat.shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh, in_specs=(in_specs, x_spec),
             out_specs=(x_spec, jax.sharding.PartitionSpec()),
             check_vma=False)(p_moe, x2d)

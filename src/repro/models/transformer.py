@@ -13,7 +13,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -236,7 +235,7 @@ def vocab_parallel_cross_entropy(x, embed_p, head_p, cfg: ModelConfig, labels,
     # inside a partial shard_map (Delta-periodic pod loop) the batch is
     # already sliced over the manual axes — the nested shard_map's specs may
     # only mention the still-automatic ones (pmean below still sees all)
-    manual = compat.manual_axes()
+    manual = shd.manual_axes()
     spec_b = tuple(a for a in baxes if a not in manual)
 
     def body(x_, w_, labels_):
@@ -259,7 +258,7 @@ def vocab_parallel_cross_entropy(x, embed_p, head_p, cfg: ModelConfig, labels,
         nll = jax.lax.pmean(nll, baxes)
         return jnp.mean(nll)[None]
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(spec_b, None, None), P(None, "model"), P(spec_b, None)),
         out_specs=P(None), check_vma=False)(x, w, labels)

@@ -21,8 +21,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 from repro.optim.optimizer import OptimizerConfig, adamw_update
@@ -52,12 +50,7 @@ def make_periodic_steps(api, mesh, opt_cfg: OptimizerConfig, *,
     sync_step(params, opt_state, acc, err)    -> (params, opt, acc, err, stats)
     """
     has_pod = "pod" in mesh.axis_names
-    # the old toolchain cannot wrap scanned models in a PARTIAL-manual
-    # shard_map (XLA check-fails on any scan-with-xs inside it); fall back to
-    # accumulating the globally-reduced gradient — semantically identical
-    # large-batch training, only without the cross-pod byte saving
-    manual_pod = has_pod and compat.PARTIAL_MANUAL_CONSTRAINT_OK
-    acc_spec = P("pod") if manual_pod else P()
+    acc_spec = P("pod") if has_pod else P()
 
     def _loss(p, b):
         with shd.use_mesh(mesh):
@@ -70,12 +63,12 @@ def make_periodic_steps(api, mesh, opt_cfg: OptimizerConfig, *,
         acc = jax.tree.map(
             lambda a, g: a + g.astype(jnp.float32)[None], acc, grads)
         out = dict(metrics, loss=loss)
-        if manual_pod:  # pods see different microbatches; replicate metrics
+        if has_pod:  # pods see different microbatches; replicate metrics
             out = jax.tree.map(lambda m: jax.lax.pmean(m, "pod"), out)
         return acc, out
 
     def sync_body(params, opt_state, acc, err):
-        if manual_pod:
+        if has_pod:
             if compress_int8:
                 red, err = compress.tree_allreduce_int8(acc, err, "pod")
                 grads = jax.tree.map(lambda g: g[0], red)
@@ -84,7 +77,7 @@ def make_periodic_steps(api, mesh, opt_cfg: OptimizerConfig, *,
                     lambda a: jax.lax.psum(a, "pod")[0] / mesh.shape["pod"],
                     acc)
         else:
-            # fallback/no-pod: every slot of the leading axis holds the same
+            # no pod axis: every slot of the leading axis holds the same
             # globally-reduced gradient
             grads = jax.tree.map(lambda a: jnp.mean(a, axis=0), acc)
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
@@ -93,13 +86,13 @@ def make_periodic_steps(api, mesh, opt_cfg: OptimizerConfig, *,
         acc = jax.tree.map(jnp.zeros_like, acc)
         return params, opt_state, acc, err, stats
 
-    if manual_pod:
+    if has_pod:
         bspec = {"tokens": P(("pod",), None)}
-        accum = jax.jit(compat.shard_map(
+        accum = jax.jit(jax.shard_map(
             accum_body, mesh=mesh, axis_names={"pod"},
             in_specs=(P(), acc_spec, bspec),
             out_specs=(acc_spec, P()), check_vma=False))
-        sync = jax.jit(compat.shard_map(
+        sync = jax.jit(jax.shard_map(
             sync_body, mesh=mesh, axis_names={"pod"},
             in_specs=(P(), P(), acc_spec, acc_spec),
             out_specs=(P(), P(), acc_spec, acc_spec, P()), check_vma=False))

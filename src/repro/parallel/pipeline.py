@@ -15,7 +15,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 
@@ -67,7 +66,7 @@ def pipeline_apply(layer_fn, stage_params, x_microbatches, mesh,
         return outs
 
     pspec = P(axis)
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: pspec, stage_params), P()),
         out_specs=P(), check_vma=False)(stage_params, x_microbatches)

@@ -20,9 +20,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 _REPLICATE_BELOW = 1 << 22          # 4M elements (~8MB bf16)
 
@@ -58,9 +57,11 @@ def batch_axes(mesh: Mesh, layout: str = None):
     return tuple(a for a in names if a in mesh.axis_names)
 
 
-def _manual_axes():
+def manual_axes() -> frozenset:
     """Mesh axes currently under manual (shard_map) control at trace time."""
-    return compat.manual_axes()
+    am = jax.sharding.get_abstract_mesh()
+    return frozenset(a for a, t in zip(am.axis_names, am.axis_types)
+                     if t == AxisType.Manual)
 
 
 def constrain(x, spec_axes):
@@ -71,9 +72,7 @@ def constrain(x, spec_axes):
     mesh = current_mesh()
     if mesh is None:
         return x
-    manual = _manual_axes()
-    if manual and not compat.PARTIAL_MANUAL_CONSTRAINT_OK:
-        return x  # old XLA: constraints inside partial shard_map crash
+    manual = manual_axes()
 
     def drop_manual(ax):
         if ax is None:

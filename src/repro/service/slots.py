@@ -38,7 +38,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import engine
 from repro.service.types import ServiceConfigError
 from repro.sim import phases as sim_phases
@@ -104,7 +103,7 @@ class SlotBatch:
 
             return jax.vmap(one)(seeds)
 
-        self.init_all = jax.jit(compat.shard_map(
+        self.init_all = jax.jit(jax.shard_map(
             init_all_body, mesh=mesh, in_specs=(P(None),),
             out_specs=sspecs, check_vma=False))
 
@@ -113,7 +112,7 @@ class SlotBatch:
             return engine.init_state(dataclasses.replace(cfg, seed=seed),
                                      rank, R, scenario)
 
-        self.init_lane = jax.jit(compat.shard_map(
+        self.init_lane = jax.jit(jax.shard_map(
             init_one_body, mesh=mesh, in_specs=(P(),), out_specs=specs,
             check_vma=False))
 
@@ -129,7 +128,7 @@ class SlotBatch:
         # the service chunk: ONE compiled trace, shared by every slot and
         # every tick (seeds are a traced argument — no retrace on tenant
         # turnover); donated carry like Simulator.run
-        self.step = jax.jit(compat.shard_map(
+        self.step = jax.jit(jax.shard_map(
             chunk_body, mesh=mesh, in_specs=(sspecs, P(None)),
             out_specs=sspecs, check_vma=False), donate_argnums=(0,))
 
@@ -146,7 +145,7 @@ class SlotBatch:
         # health re-probe of the CURRENT stacked state (per-slot verdict
         # on exactly what a snapshot would capture — DESIGN.md §10 rule
         # "every rollback target is verified-good", now per slot)
-        self._probe = jax.jit(compat.shard_map(
+        self._probe = jax.jit(jax.shard_map(
             probe_body, mesh=mesh, in_specs=(sspecs, P(None)),
             out_specs=P(None, "ranks"), check_vma=False))
 

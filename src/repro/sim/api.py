@@ -25,7 +25,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro import telemetry
 from repro.checkpoint import manager
 from repro.connectome import routing
@@ -72,7 +71,7 @@ class Simulator:
                 rank = jax.lax.axis_index("ranks")
                 return engine.init_state(cfg, rank, self.num_ranks, scenario)
 
-            self.init_fn = jax.jit(compat.shard_map(
+            self.init_fn = jax.jit(jax.shard_map(
                 init_body, mesh=self.mesh, in_specs=(), out_specs=self.specs,
                 check_vma=False))
 
@@ -84,7 +83,7 @@ class Simulator:
 
             # the un-jitted shard_map'd chunk: `step` jits it directly,
             # `run` scans it — both drive the SAME traced computation
-            self._chunk_shard = compat.shard_map(
+            self._chunk_shard = jax.shard_map(
                 chunk_body, mesh=self.mesh, in_specs=(self.specs,),
                 out_specs=self.specs, check_vma=False)
             self.chunk_fn = jax.jit(self._chunk_shard, donate_argnums=(0,))
@@ -217,7 +216,7 @@ class Simulator:
                 return sim_phases.sim_chunk(st, ctx)
 
             dyn_specs = jax.tree.map(lambda _: P(), dyn)
-            self._dyn_fn = jax.jit(compat.shard_map(
+            self._dyn_fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=(self.specs, dyn_specs),
                 out_specs=self.specs, check_vma=False), donate_argnums=(0,))
         with telemetry.span("sim.step_with"):
@@ -344,7 +343,7 @@ class Simulator:
                 stats = sim_phases.health_verdict(st, ctx)
                 return stats.gauges["health_flags"]
 
-            self._probe_fn = jax.jit(compat.shard_map(
+            self._probe_fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=(self.specs,),
                 out_specs=P("ranks"), check_vma=False))
         flags = jax.device_get(self._probe_fn(self.state))
@@ -374,7 +373,7 @@ class Simulator:
                 return st._replace(subs=subs, rate_slots=rate_slots,
                                    remote_rates=remote_rates)
 
-            self._rebuild_fn = jax.jit(compat.shard_map(
+            self._rebuild_fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=(self.specs,),
                 out_specs=self.specs, check_vma=False))
         with telemetry.span("sim.rebuild_exchange"):
@@ -395,12 +394,20 @@ class Simulator:
         with telemetry.span("sim.metrics"):
             return jax.device_get(self.state.stats)
 
-    def lower(self):
+    def lower(self, num_chunks: Optional[int] = None):
         """Lower one sim chunk at the global sharded shapes — scenario
         included, so the dry-run/roofline path sees the trace that will
-        actually run (stimulus tables, population params, lesion masks)."""
+        actually run (stimulus tables, population params, lesion masks).
+        With ``num_chunks``, lower the program ``run(num_chunks)`` calls
+        instead; compiling it ahead leaves the executable in jit's
+        in-memory cache, so that ``run`` compiles nothing."""
+        fn = self.chunk_fn if num_chunks is None else \
+            self._run_fn(int(num_chunks), False)
+        shapes = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            jax.eval_shape(self.init_fn), self.shardings())
         with telemetry.span("sim.lower"):
-            return self.chunk_fn.lower(jax.eval_shape(self.init_fn))
+            return fn.lower(shapes)
 
     # ------------------------------------------------------------ persist
     def ckpt_metadata(self) -> dict:

@@ -108,17 +108,20 @@ def roofline_block(hlo_text: str, num_ranks: int) -> dict:
     """Analytic bytes/FLOPs of one compiled sim chunk
     (``launch/roofline.py`` over the post-SPMD optimized HLO): collective
     wire bytes by kind, dot FLOPs, materialized HBM bytes, and the
-    TPU-model roofline terms — the third telemetry source next to the
-    measured counters and the wall-clock spans."""
+    roofline terms priced on the TPU v5e peaks (a model of that target,
+    not a measurement) — the third telemetry source next to the measured
+    counters and the wall-clock spans."""
     from repro.launch import roofline as rl
     ana = rl.analyze_hlo(hlo_text, num_ranks)
     mat = rl.materialized_bytes(hlo_text)
     terms = rl.roofline_terms(ana["dot_flops"], mat,
-                              ana["collective_bytes_total"])
+                              ana["collective_bytes_total"],
+                              device_kind=rl.V5E)
     return {"collective_wire_bytes": ana["collective_wire_bytes"],
             "collective_bytes_total": ana["collective_bytes_total"],
             "dot_flops": ana["dot_flops"],
             "materialized_hbm_bytes": mat,
+            "target_device_kind": rl.V5E,
             "terms": terms}
 
 

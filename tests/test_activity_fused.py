@@ -225,7 +225,6 @@ def test_fused_hbm_bytes_drop_3x():
     streaming traffic must be >= 3x below the reference lowering's
     materialized buffers (acceptance criterion; bench_activity records the
     absolute numbers)."""
-    from repro import compat
     from repro.launch import roofline
     cfg = dataclasses.replace(SMALL, rate_period=100)
     mesh = engine.make_brain_mesh()
@@ -237,8 +236,8 @@ def test_fused_hbm_bytes_drop_3x():
         rank = jax.lax.axis_index("ranks")
         return engine.activity_phase(st, cfg, rank, "ranks", num_ranks)
 
-    act = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(specs,),
-                                   out_specs=specs, check_vma=False))
+    act = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                out_specs=specs, check_vma=False))
     init_fn, _ = engine.build_sim(cfg, mesh)
     hlo = act.lower(init_fn()).compile().as_text()
     ref_bytes = roofline.materialized_bytes(hlo) / cfg.rate_period

@@ -164,3 +164,16 @@ def test_kernel_engine_integration():
     d2 = jnp.sum((x[:, None] - y[None]) ** 2, -1)
     expected = w * _gauss(d2, 0.25)
     np.testing.assert_allclose(np.asarray(p), np.asarray(expected), rtol=1e-5)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    """Kernels compile on a TPU and are interpreted on the CPU only; any
+    other backend is refused rather than silently interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=backend):
+            ops._interpret_default()
+    else:
+        assert ops._interpret_default() is interpret

@@ -439,7 +439,7 @@ def test_moe_strategies_agree_on_mesh():
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.parallel import sharding as shd
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ('data', 'model'))
         cfg0 = get_smoke_config('arctic-480b').replace(
             scan_layers=True, capacity_factor=4.0)
@@ -472,7 +472,7 @@ def test_periodic_sync_equals_direct_when_delta_1():
         from repro.optim.optimizer import (OptimizerConfig, adamw_update,
                                            init_opt_state)
         from repro.parallel import sharding as shd
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
         cfg = get_smoke_config('qwen2-7b').replace(dtype='float32')
         api = build_model(cfg)
@@ -506,7 +506,7 @@ def test_pipeline_parallel_equals_sequential():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.pipeline import pipeline_apply
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4,), ('stage',))
         L, d = 8, 16
         ks = jax.random.split(jax.random.key(0), L)
@@ -568,13 +568,13 @@ def test_int8_compressed_sync_close_to_exact():
         import jax, jax.numpy as jnp
         from repro.parallel.compress import allreduce_int8
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((8,), ('pod',))
         x = jax.random.normal(jax.random.key(0), (8, 128))
         def body(xl):
             red, err = allreduce_int8(xl[0], jnp.zeros_like(xl[0]), 'pod')
             return red[None], err[None]
-        red, err = jax.jit(shard_map(
+        red, err = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P('pod'),),
             out_specs=(P('pod'), P('pod')), check_vma=False))(x)
         exact = jnp.mean(x, 0)

@@ -72,8 +72,16 @@ def test_real_compile_collectives_nonzero():
 
 
 def test_roofline_terms_dominance():
-    t = rl.roofline_terms(1e15, 1e9, 1e9)     # compute-bound
+    t = rl.roofline_terms(1e15, 1e9, 1e9, device_kind=rl.V5E)  # compute
     assert t["dominant"] == "compute" and t["roofline_fraction"] == 1.0
-    t = rl.roofline_terms(1e12, 1e9, 1e12)    # collective-bound
+    t = rl.roofline_terms(1e12, 1e9, 1e12, device_kind=rl.V5E)  # collective
     assert t["dominant"] == "collective"
     assert t["roofline_fraction"] < 1.0
+
+
+def test_roofline_peaks_unknown_device_kind_raises():
+    """Peaks come from the published table only: a device kind that is not
+    in it (the CPU backend, say) is an error, not a silent v5e default."""
+    assert rl.peaks(rl.V5E)["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="cpu"):
+        rl.roofline_terms(1.0, 1.0, 1.0, device_kind="cpu")

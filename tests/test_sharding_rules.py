@@ -3,7 +3,7 @@ compiles; hypothesis sweeps)."""
 import jax
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config

@@ -333,6 +333,36 @@ def _gate():
     return check_regression
 
 
+def _bench_util():
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    try:
+        from benchmarks import _util
+    finally:
+        sys.path.pop(0)
+    return _util
+
+
+def test_bench_num_ranks_must_match_requested_devices(monkeypatch):
+    """A bench asked for R ranks through the host-device flag runs on
+    exactly R devices or refuses (the flag makes CPU devices only)."""
+    util = _bench_util()
+    r = len(jax.devices())
+    monkeypatch.setenv("XLA_FLAGS",
+                       f"--xla_force_host_platform_device_count={r}")
+    assert util.num_ranks() == r
+    monkeypatch.setenv("XLA_FLAGS",
+                       f"--xla_force_host_platform_device_count={r + 3}")
+    with pytest.raises(RuntimeError, match=f"asked for {r + 3} ranks"):
+        util.num_ranks()
+
+
+def test_bench_run_sub_fails_when_child_fails():
+    """No ERROR row in a table that exits 0: the parent fails too."""
+    util = _bench_util()
+    with pytest.raises(SystemExit, match="no_such_bench failed"):
+        util.run_sub("benchmarks.no_such_bench", 1, timeout=120)
+
+
 def _report(cases):
     return {"bench": "x", "smoke": False, "cases": cases}
 
