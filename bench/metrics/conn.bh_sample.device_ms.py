@@ -1,0 +1,13 @@
+"""Device time per chunk of phase B's sampling after the expansion rounds:
+node statistics, Gumbel draws, argmax and the pick gathers
+(``repro.bh.sample`` under ``repro.conn.formation``). None where the
+program has no such scope."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    ns = [v for k, v in run.trace.scope_ns.items()
+          if "repro.conn.formation" in k.split("/")
+          and k.rsplit("/", 1)[-1] == "repro.bh.sample"]
+    return sum(ns) / 1e6 / run.chunks if ns else None

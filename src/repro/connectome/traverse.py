@@ -9,8 +9,14 @@ selects the target; sampling an inner node restarts the expansion from it.
 
 Static-shape deviations (documented in DESIGN.md §2/§6): the frontier is
 capped at F entries — parents whose children would overflow are kept as
-sampling candidates at coarser granularity; overflow is counted and reported
-by tests.
+sampling candidates at coarser granularity; ``phase_b_core`` returns each
+query's overflow flag, and the telemetry counts the live ones
+(``bh_frontier_overflow``).
+
+Named scopes mark the stages for the profiler: ``repro.bh.search`` (the
+restart loop), ``repro.bh.expand`` (frontier set-up and expansion rounds),
+``repro.bh.sample`` (the Gumbel pick) and ``repro.bh.member`` (member
+selection). They change only HLO metadata.
 
 PRNG contract: every Gumbel draw comes from the counter-based Threefry hash
 (kernels/hash.py) keyed by ``(seed, BH_DOMAIN, bh_ctr(chunk, round, draw),
@@ -146,8 +152,31 @@ def expand_and_sample(tree: StackedTree, x, root_cell, root_rel, src_gid, rnd,
     """
     q = x.shape[0]
     f = frontier
-    last = n_levels - 1
+    with jax.named_scope("repro.bh.expand"):
+        cells, lvls, valid, overflow = _expand(
+            tree, x, root_cell, root_rel, sigma=sigma, theta=theta, f=f,
+            n_levels=n_levels)
 
+    with jax.named_scope("repro.bh.sample"):
+        cnt, prob, _ = _node_stats(tree, lvls, cells, x, sigma)
+        logits = jnp.where(valid & (cnt > 1e-9),
+                           jnp.log(jnp.maximum(prob, 1e-30)), NEG)
+        g = chash.gumbel(seed, chash.BH_DOMAIN,
+                         chash.bh_ctr(chunk, rnd, jnp.arange(f))[None, :],
+                         src_gid[:, None])
+        pick = jnp.argmax(logits + g, axis=1)
+        qi = jnp.arange(q)
+        any_valid = jnp.any(logits > NEG / 2, axis=1)
+        return (cells[qi, pick], lvls[qi, pick], any_valid, overflow)
+
+
+def _expand(tree: StackedTree, x, root_cell, root_rel, *, sigma, theta,
+            f: int, n_levels: int):
+    """The frontier set-up and the ``n_levels`` expansion rounds of
+    ``expand_and_sample``. Returns (cells, lvls, valid, overflow): the
+    final (Q, F) frontier and the (Q,) overflow flags."""
+    q = x.shape[0]
+    last = n_levels - 1
     # init: children of root (or root itself if already deepest)
     at_leaf = root_rel >= last
     child_rel = jnp.where(at_leaf, root_rel, root_rel + 1)
@@ -203,18 +232,7 @@ def expand_and_sample(tree: StackedTree, x, root_cell, root_rel, src_gid, rnd,
 
     state = (cells0, lvls0, valid0, overflow0)
     state, _ = jax.lax.scan(round_fn, state, None, length=n_levels)
-    cells, lvls, valid, overflow = state
-
-    cnt, prob, _ = _node_stats(tree, lvls, cells, x, sigma)
-    logits = jnp.where(valid & (cnt > 1e-9), jnp.log(jnp.maximum(prob, 1e-30)),
-                       NEG)
-    g = chash.gumbel(seed, chash.BH_DOMAIN,
-                     chash.bh_ctr(chunk, rnd, jnp.arange(f))[None, :],
-                     src_gid[:, None])
-    pick = jnp.argmax(logits + g, axis=1)
-    qi = jnp.arange(q)
-    any_valid = jnp.any(logits > NEG / 2, axis=1)
-    return (cells[qi, pick], lvls[qi, pick], any_valid, overflow)
+    return state
 
 
 def bh_search(tree: StackedTree, x, src_gid, start_cell, *, seed: int, chunk,
@@ -249,12 +267,13 @@ def bh_search(tree: StackedTree, x, src_gid, start_cell, *, seed: int, chunk,
         done = done | (rel >= last) | ~valid
         return (cell, rel, valid, done, overflow, depth)
 
-    st = (start_cell.astype(jnp.int32), jnp.zeros((q,), jnp.int32),
-          jnp.ones((q,), bool), jnp.zeros((q,), bool), jnp.zeros((q,), bool),
-          jnp.zeros((q,), jnp.int32))
-    cell, rel, valid, done, overflow, depth = jax.lax.fori_loop(
-        0, restarts, body, st)
-    valid = valid & (rel >= last)
+    with jax.named_scope("repro.bh.search"):
+        st = (start_cell.astype(jnp.int32), jnp.zeros((q,), jnp.int32),
+              jnp.ones((q,), bool), jnp.zeros((q,), bool),
+              jnp.zeros((q,), bool), jnp.zeros((q,), jnp.int32))
+        cell, rel, valid, done, overflow, depth = jax.lax.fori_loop(
+            0, restarts, body, st)
+        valid = valid & (rel >= last)
     return cell, valid, overflow, depth
 
 
@@ -313,27 +332,29 @@ def phase_b_core(counts, cents, leaf_members, neuron_pos, vacant_d, x,
     M); neuron_pos/vacant_d: the subtree's neuron data;
     x/start_cell_rel/src_gid/valid_in: (Q, ...) queries; chunk/gid_base:
     traced i32 scalars.
-    Returns (target_gid (Q,), valid (Q,), depth (Q,) i32 restart rounds)."""
+    Returns (target_gid (Q,), valid (Q,), depth (Q,) i32 restart rounds,
+    overflow (Q,) bool: the query's frontier overflowed in some round)."""
     tree = StackedTree(counts, cents, tuple(sizes), 0)
-    leaf_cell, valid, _, depth = bh_search(
+    leaf_cell, valid, overflow, depth = bh_search(
         tree, x, src_gid, start_cell_rel, seed=seed, chunk=chunk, theta=theta,
         sigma=sigma, frontier=frontier, n_levels=n_levels,
         round_base=PHASE_B_ROUND_BASE)
     valid = valid & valid_in
-    members = leaf_members[leaf_cell]                  # (Q, M) local ids
-    mvalid = members >= 0
-    msafe = jnp.where(mvalid, members, 0)
-    mgid = gid_base + msafe
-    # exclude self-connection (a neuron never proposes to itself)
-    mvalid = mvalid & (mgid != src_gid[:, None])
-    mpos = neuron_pos[msafe]
-    mw = jnp.where(mvalid, vacant_d[msafe], 0.0)
-    pick, pvalid = select_member(x, mpos, mw, mvalid, src_gid, seed=seed,
-                                 chunk=chunk, sigma=sigma)
-    tgt_local = jnp.take_along_axis(msafe, pick[:, None], axis=1)[:, 0]
-    tgt_gid = gid_base + tgt_local
-    ok = valid & pvalid
-    return jnp.where(ok, tgt_gid, -1), ok, depth
+    with jax.named_scope("repro.bh.member"):
+        members = leaf_members[leaf_cell]              # (Q, M) local ids
+        mvalid = members >= 0
+        msafe = jnp.where(mvalid, members, 0)
+        mgid = gid_base + msafe
+        # exclude self-connection (a neuron never proposes to itself)
+        mvalid = mvalid & (mgid != src_gid[:, None])
+        mpos = neuron_pos[msafe]
+        mw = jnp.where(mvalid, vacant_d[msafe], 0.0)
+        pick, pvalid = select_member(x, mpos, mw, mvalid, src_gid, seed=seed,
+                                     chunk=chunk, sigma=sigma)
+        tgt_local = jnp.take_along_axis(msafe, pick[:, None], axis=1)[:, 0]
+        tgt_gid = gid_base + tgt_local
+        ok = valid & pvalid
+        return jnp.where(ok, tgt_gid, -1), ok, depth, overflow
 
 
 @registry.register_phase("traversal", "reference")
@@ -360,6 +381,13 @@ def phase_b_fused(stacked, local, neuron_pos, vacant_d, pos,
         chunk, gid_base, interpret=interpret, **kw)
 
 
+def phase_b_levels(cfg) -> int:
+    """Levels of the subtree phase B searches, its branch node included.
+    Phase B gives ``bh_search`` no ``max_restarts``, so this is also the
+    number of restart iterations it runs over every query row."""
+    return cfg.local_levels + 1
+
+
 def phase_b(local, neuron_pos, vacant_d, pos, src_gid, start_cell_rel,
             valid_in, cfg, num_ranks: int, gid_base, *, chunk,
             interpret=None):
@@ -373,7 +401,7 @@ def phase_b(local, neuron_pos, vacant_d, pos, src_gid, start_cell_rel,
     stacked = stack_levels(local.counts, local.centroids, b)
     kw = dict(seed=cfg.seed, sizes=stacked.sizes, theta=cfg.theta,
               sigma=cfg.sigma, frontier=cfg.frontier_cap,
-              n_levels=cfg.local_levels + 1)
+              n_levels=phase_b_levels(cfg))
     impl = registry.resolve("traversal", cfg.connectivity_impl)
     return impl(stacked, local, neuron_pos, vacant_d, pos, start_cell_rel,
                 src_gid, valid_in, chunk, gid_base, kw, interpret=interpret)

@@ -41,16 +41,18 @@ def formation_phase_new(ctx, state, local_tree, vac_d_pos, out_edges,
                         valid_a, k_accept, stats):
     """Paper's NEW algorithm: ship 42B formation-and-calculation requests
     to the rank that owns the target subtree (move compute to the data)."""
-    tgt_gid, accept, ovf, (depth, processed) = routing.formation_new(
+    tgt_gid, accept, ovf, searched = routing.formation_new(
         ctx.cfg, state.positions, local_tree, vac_d_pos, in_edges, gids,
         branch_cell, owner, start_rel, valid_a, ctx.rank, ctx.axis_name,
         ctx.num_ranks, k_accept, state.chunk)
     in_edges = accept.pop("in_edges")
     stats = stats.count("request_overflow", ovf)
     stats = stats.count("bh_responses", jnp.sum(accept["accepted"]))
-    # restart depths of the phase-B searches THIS rank executed (the
-    # received requests) — identical under both traversal lowerings
-    stats = ctx.metrics.traversal(stats, depth, processed)
+    # restart depths and frontier overflow of the phase-B searches THIS
+    # rank executed (the received requests) — identical under both
+    # traversal lowerings
+    stats = ctx.metrics.traversal(stats, *searched,
+                                  traverse.phase_b_levels(ctx.cfg))
     out_edges = syn.add_out_edges(out_edges, tgt_gid, accept["accepted"])
     stats = stats.count("synapses_formed", jnp.sum(accept["accepted"]))
     return out_edges, in_edges, stats
@@ -62,15 +64,17 @@ def formation_phase_old(ctx, state, local_tree, vac_d_pos, out_edges,
                         valid_a, k_accept, stats):
     """Paper's OLD baseline: download every remote subtree + leaf neuron
     data ("RMA download with caching") and finish the search locally."""
-    tgt_gid, accepted, new_in, downloaded, (depth, searched) = \
+    tgt_gid, accepted, new_in, downloaded, searched = \
         routing.formation_old(
             ctx.cfg, state.positions, local_tree, vac_d_pos, in_edges, gids,
             branch_cell, valid_a, ctx.rank, ctx.axis_name, ctx.num_ranks,
             k_accept, state.chunk)
     out_edges = syn.add_out_edges(out_edges, tgt_gid, accepted)
     stats = stats.count("tree_nodes_downloaded", downloaded)
-    # restart depths of MY searchers against the downloaded global tree
-    stats = ctx.metrics.traversal(stats, depth, searched)
+    # restart depths and frontier overflow of MY searchers against the
+    # downloaded global tree
+    stats = ctx.metrics.traversal(stats, *searched,
+                                  traverse.phase_b_levels(ctx.cfg))
     stats = stats.count("synapses_formed", jnp.sum(accepted))
     return out_edges, new_in, stats
 

@@ -114,16 +114,17 @@ def step_core(state, in_edges, w_table, rates, bg_mean, bg_std, izh,
     ca_decay, ca_beta = ca_consts
 
     # ---- (a) synaptic input from the in-edge table -----------------------
-    local_in = local_spike_hits(spiked, in_edges, rank, n)
-    if remote_override is None:
-        remote_in = reconstruct_remote_spikes(seed, gstep, rates, in_edges,
-                                              rank, n, rate_slots=rate_slots)
-    else:
-        remote_in = remote_override
-    valid = in_edges >= 0
-    src_lid = jnp.where(valid, in_edges, 0) % n
-    weights = jnp.where(valid, w_table[src_lid], 0.0)
-    syn_in = jnp.sum((local_in | remote_in) * weights, axis=-1)
+    with jax.named_scope("repro.act.input"):
+        local_in = local_spike_hits(spiked, in_edges, rank, n)
+        if remote_override is None:
+            remote_in = reconstruct_remote_spikes(
+                seed, gstep, rates, in_edges, rank, n, rate_slots=rate_slots)
+        else:
+            remote_in = remote_override
+        valid = in_edges >= 0
+        src_lid = jnp.where(valid, in_edges, 0) % n
+        weights = jnp.where(valid, w_table[src_lid], 0.0)
+        syn_in = jnp.sum((local_in | remote_in) * weights, axis=-1)
 
     # ---- (b) background noise + stimulation ------------------------------
     gid = rank * n + jnp.arange(n, dtype=jnp.int32)

@@ -31,10 +31,11 @@ from repro.connectome.traverse import phase_b_core
 
 def _kernel(counts_ref, cents_ref, members_ref, npos_ref, vac_ref, x_ref,
             start_ref, gid_ref, valid_ref, scal_ref, tgt_ref, ok_ref,
-            depth_ref, *, seed, sizes, theta, sigma, frontier, n_levels):
+            depth_ref, ovf_ref, *, seed, sizes, theta, sigma, frontier,
+            n_levels):
     chunk = scal_ref[0]
     gid_base = scal_ref[1]
-    tgt, ok, depth = phase_b_core(
+    tgt, ok, depth, overflow = phase_b_core(
         counts_ref[...], cents_ref[...], members_ref[...], npos_ref[...],
         vac_ref[...], x_ref[...], start_ref[...], gid_ref[...],
         valid_ref[...], chunk, gid_base, seed=seed, sizes=sizes, theta=theta,
@@ -42,6 +43,7 @@ def _kernel(counts_ref, cents_ref, members_ref, npos_ref, vac_ref, x_ref,
     tgt_ref[...] = tgt.astype(jnp.int32)
     ok_ref[...] = ok
     depth_ref[...] = depth.astype(jnp.int32)
+    ovf_ref[...] = overflow
 
 
 def bh_traverse(counts, cents, members, npos, vac, x, start_cell, src_gid,
@@ -55,7 +57,8 @@ def bh_traverse(counts, cents, members, npos, vac, x, start_cell, src_gid,
     start_cell/src_gid: (Q,) i32; valid: (Q,) bool; chunk/gid_base: traced
     i32 scalars; sizes: static per-level cell edge lengths. Returns
     (target_gid (Q,) i32, valid (Q,), depth (Q,) i32 restart rounds — the
-    telemetry frontier-depth signal).
+    telemetry frontier-depth signal — and overflow (Q,) bool, the frontier
+    overflow flag the telemetry counts).
 
     Q that is not a multiple of the block is padded up to it (padded rows
     carry valid=False and are sliced off — same fix as ``neuron_step``)."""
@@ -75,19 +78,20 @@ def bh_traverse(counts, cents, members, npos, vac, x, start_cell, src_gid,
     kern = functools.partial(_kernel, seed=seed, sizes=tuple(sizes),
                              theta=theta, sigma=sigma, frontier=frontier,
                              n_levels=n_levels)
-    tgt, ok, depth = pl.pallas_call(
+    outs = pl.pallas_call(
         kern,
         grid=(qp // bq,),
         in_specs=[full(counts), full(cents), full(members), full(npos),
                   full(vac), pl.BlockSpec((bq, 3), lambda i: (i, 0)),
                   row, row, row, pl.BlockSpec((2,), lambda i: (0,))],
-        out_specs=[row, row, row],
+        out_specs=[row, row, row, row],
         out_shape=[jax.ShapeDtypeStruct((qp,), jnp.int32),
                    jax.ShapeDtypeStruct((qp,), jnp.bool_),
-                   jax.ShapeDtypeStruct((qp,), jnp.int32)],
+                   jax.ShapeDtypeStruct((qp,), jnp.int32),
+                   jax.ShapeDtypeStruct((qp,), jnp.bool_)],
         interpret=interpret,
     )(counts, cents, members, npos, vac, x, start_cell, src_gid, valid, scal)
-    return (tgt[:q], ok[:q], depth[:q]) if qp != q else (tgt, ok, depth)
+    return tuple(o[:q] for o in outs) if qp != q else tuple(outs)
 
 
 def traverse_hbm_bytes(n_levels: int, c_max: int, n_leaf: int,
@@ -95,12 +99,13 @@ def traverse_hbm_bytes(n_levels: int, c_max: int, n_leaf: int,
     """Analytic HBM traffic of one fused phase-B on TPU: the tree arrays,
     membership table, and neuron data stream HBM->VMEM once (constant index
     maps keep them block-resident across the query grid), queries stream in
-    once, the two outputs stream out once — the per-round (Q, F) frontier
+    once, the outputs stream out once — the per-round (Q, F) frontier
     state never leaves VMEM. Compare with the roofline-counted bytes of the
     reference lowering (benchmarks/bench_connectivity.py)."""
     tree = n_levels * c_max * 4 + n_levels * c_max * 3 * 4
     leaf = n_leaf * members_cap * 4
     neurons = n * 3 * 4 + n * 4
     queries = q * 3 * 4 + q * 4 + q * 4 + q + 8
-    outs = q * 4 + q + q * 4   # target gid + valid + telemetry depth
+    # target gid + valid + telemetry depth + telemetry overflow
+    outs = q * 4 + q + q * 4 + q
     return tree + leaf + neurons + queries + outs

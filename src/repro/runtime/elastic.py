@@ -161,6 +161,7 @@ def remesh_restore_brain(ckpt_dir: str, cfg, mesh=None, step=None,
                     f"{key}: shape {arr.shape} != {leaf.shape}")
         out.append(jax.device_put(np.asarray(arr), shard_leaves[i][1]))
     sim._state = jax.tree_util.tree_unflatten(treedef, out)
+    sim.host_chunk = int(step)
     # re-derive the sparse registry for THIS rank count (no-op for dense)
     sim.rebuild_exchange()
     sim.lifecycle.update({k: int(v) for k, v in

@@ -190,6 +190,7 @@ class SimulationRunner:
                         ValueError):
                     continue
                 self.sim._state = tree
+                self.sim.host_chunk = step
                 self.sim.lifecycle["checkpoint_restores"] += 1
                 if self.sim.probe_health() == 0:
                     return step

@@ -48,7 +48,10 @@ class Simulator:
     ``telemetry.span`` (wall-clock records + jax.profiler trace
     annotations; read back via ``telemetry.spans()``), and
     ``profile_dir=...`` wraps every ``run`` in a profiler capture
-    (one trace directory per run, viewable in Perfetto/XProf).
+    (one trace directory per run, viewable in Perfetto/XProf). ``run``,
+    ``step`` and ``step_with`` open their spans as profiler step markers
+    numbered by ``host_chunk``, and publish the new state's metrics,
+    unfetched, for ``telemetry.last_chunk_counters``.
     """
 
     def __init__(self, cfg, scenario=None, mesh=None, profile_dir=None):
@@ -89,6 +92,9 @@ class Simulator:
             self.chunk_fn = jax.jit(self._chunk_shard, donate_argnums=(0,))
             self._run_cache = {}
             self._state = None
+            # the chunk counter of the current state, kept on the host:
+            # numbers the step markers without a read from the device
+            self.host_chunk = 0
             self._probe_fn = None
             self._rebuild_fn = None
             self._dyn_fn = None
@@ -188,13 +194,24 @@ class Simulator:
         """(Re)initialize from cfg.seed and return the fresh state."""
         with telemetry.span("sim.init"):
             self._state = self.init_fn()
+        self.host_chunk = 0
         return self._state
+
+    def _advanced(self, num_chunks: int) -> None:
+        """Count chunks just dispatched, and hand the new state's metrics
+        and chunk counter to the telemetry as device references (no
+        transfer). The dispatch that donates this state replaces them
+        right after."""
+        self.host_chunk += num_chunks
+        telemetry.publish_latest(self._state.stats, self._state.chunk)
 
     # ------------------------------------------------------------ driving
     def step(self):
         """Advance one chunk (Delta activity steps + connectivity update)."""
-        with telemetry.span("sim.step"):
-            self._state = self.chunk_fn(self.state)
+        state = self.state
+        with telemetry.span("sim.step", step_num=self.host_chunk):
+            self._state = self.chunk_fn(state)
+        self._advanced(1)
         return self._state
 
     def step_with(self, dyn):
@@ -219,8 +236,10 @@ class Simulator:
             self._dyn_fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=(self.specs, dyn_specs),
                 out_specs=self.specs, check_vma=False), donate_argnums=(0,))
-        with telemetry.span("sim.step_with"):
-            self._state = self._dyn_fn(self.state, dyn)
+        state = self.state
+        with telemetry.span("sim.step_with", step_num=self.host_chunk):
+            self._state = self._dyn_fn(state, dyn)
+        self._advanced(1)
         return self._state
 
     def dyn_compile_count(self) -> int:
@@ -239,13 +258,15 @@ class Simulator:
         returned: ``state, rec = sim.run(k, recorder=rec)``. Without it,
         returns the final state.
 
-        Runs under a ``telemetry.span``; with ``profile_dir`` set, the
-        whole call (fenced by ``block_until_ready``) is captured as one
-        profiler trace under ``<profile_dir>/``."""
+        Runs under a ``telemetry.span``, a step marker numbered by the
+        first chunk it advances; with ``profile_dir`` set, the whole call
+        (fenced by ``block_until_ready``) is captured as one profiler
+        trace under ``<profile_dir>/``."""
         state = self.state   # init outside the run span/capture
         fn = self._run_fn(int(num_chunks), recorder is not None)
-        with telemetry.span("sim.run", chunks=int(num_chunks)), \
-                telemetry.profile(self.profile_dir):
+        with telemetry.profile(self.profile_dir), \
+                telemetry.span("sim.run", step_num=self.host_chunk,
+                               chunks=int(num_chunks)):
             if recorder is None:
                 self._state = fn(state)
                 out = self._state
@@ -256,6 +277,7 @@ class Simulator:
                 # fence so the capture contains the device work, not just
                 # the async dispatch
                 jax.block_until_ready(self._state)
+        self._advanced(int(num_chunks))
         return out
 
     def _run_fn(self, k: int, with_recorder: bool):
@@ -447,5 +469,6 @@ class Simulator:
             target = jax.eval_shape(self.init_fn)
             tree, _ = manager.restore(path, step, target, self.shardings())
             self._state = tree
+        self.host_chunk = int(step)
         self.lifecycle["checkpoint_restores"] += 1
         return step

@@ -38,7 +38,7 @@ bit-identical physics counters (tests/test_telemetry.py). Bucket weights
 are 0/1 and counts are small integers, so the f32 scatter-adds are exact
 and order-independent.
 
-This module is import-light (jax only) — the engine, kernels, and
+This module is import-light (jax and numpy only) — the engine, kernels, and
 connectome all import it without cycles.
 """
 from __future__ import annotations
@@ -48,6 +48,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 # the 11 legacy byte-accounting counters (paper Tables I/II) ...
@@ -57,7 +58,8 @@ LEGACY_KEYS = ("spikes_sent", "rates_sent", "subscription_requests",
                "tree_nodes_downloaded", "request_overflow")
 # ... plus the per-phase work counters added with the telemetry layer
 EXTRA_KEYS = ("activity_steps", "activity_spikes", "tree_nodes_built",
-              "bh_restarts")
+              "bh_restarts", "bh_query_slots", "bh_queries_live",
+              "bh_rounds_run", "bh_frontier_overflow")
 COUNTER_KEYS = LEGACY_KEYS + EXTRA_KEYS
 
 # counter -> phase of the three-phase loop it instruments; the report
@@ -67,7 +69,9 @@ PHASE_OF = {
     "spikes_sent": "activity",
     "tree_nodes_built": "tree_build", "tree_nodes_downloaded": "tree_build",
     "bh_requests": "phase_b", "bh_responses": "phase_b",
-    "bh_restarts": "phase_b", "formation_requests": "phase_b",
+    "bh_restarts": "phase_b", "bh_query_slots": "phase_b",
+    "bh_queries_live": "phase_b", "bh_rounds_run": "phase_b",
+    "bh_frontier_overflow": "phase_b", "formation_requests": "phase_b",
     "request_overflow": "phase_b",
     "synapses_formed": "synapse_update", "synapses_deleted": "synapse_update",
     "rates_sent": "exchange", "subscription_requests": "exchange",
@@ -233,13 +237,24 @@ class Recorder:
                     for c in local_tree.counts)
         return m.count("tree_nodes_built", built)
 
-    def traversal(self, m: Metrics, depth, mask) -> Metrics:
-        """Record phase-B restart depths for the queries in ``mask``:
-        the ``bh_restarts`` total and the frontier-depth histogram. The
-        depths come out of ``bh_search`` identically under both
-        traversal lowerings."""
+    def traversal(self, m: Metrics, depth, mask, overflow,
+                  restarts: int) -> Metrics:
+        """Record one phase B over its (Q,) query rows, ``mask`` the rows
+        holding a request: ``bh_query_slots`` (Q), ``bh_queries_live``,
+        ``bh_rounds_run`` (Q x the ``restarts`` iterations the restart loop
+        runs over every row), ``bh_restarts`` (the iterations live rows
+        needed), ``bh_frontier_overflow`` (live rows whose frontier
+        overflowed) and the frontier-depth histogram. Depths and overflow
+        flags come out of ``bh_search`` identically under both traversal
+        lowerings."""
         w = mask.astype(jnp.float32)
+        slots = mask.shape[0]
+        m = m.count("bh_query_slots", jnp.float32(slots))
+        m = m.count("bh_queries_live", jnp.sum(w))
+        m = m.count("bh_rounds_run", jnp.float32(slots * restarts))
         m = m.count("bh_restarts", jnp.sum(depth.astype(jnp.float32) * w))
+        m = m.count("bh_frontier_overflow",
+                    jnp.sum(overflow.astype(jnp.float32) * w))
         nb = HIST_BUCKETS["frontier_depth"]
         bucket = jnp.clip(depth, 0, nb - 1)
         return m.observe("frontier_depth", bucket, w)
@@ -253,3 +268,42 @@ class Recorder:
         nb = HIST_BUCKETS["subs_occupancy"]
         bucket = jnp.clip((frac * nb).astype(jnp.int32), 0, nb - 1)
         return m.observe("subs_occupancy", bucket[None])
+
+
+# ==================================================================
+# The process's latest metrics. ``Simulator.run``/``step``/``step_with``
+# hand their output state's metrics and chunk counter here as device
+# references — no transfer on the chunk path; ``last_chunk_counters``
+# fetches the per-chunk ring only when it is called.
+# ==================================================================
+_latest = None
+
+
+def publish_latest(stats: Metrics, chunk) -> None:
+    """Keep ``stats`` and the ``chunk`` counter (chunks run) of the newest
+    state as the process's latest metrics, unfetched."""
+    global _latest
+    _latest = (stats, chunk)
+
+
+def last_chunk_counters(k: int):
+    """The counter increments of each of the last ``k`` chunks of the
+    latest published state, from its per-chunk ring, summed over ranks:
+    ``{key: (k,) float64 array}``, oldest chunk first. One transfer, made
+    now. None when no state was published, or its buffers were donated
+    since. ``k`` may exceed neither the chunks run nor the ring's
+    length."""
+    if _latest is None:
+        return None
+    if any(getattr(x, "is_deleted", lambda: False)()
+           for x in jax.tree.leaves(_latest)):
+        return None
+    rings, chunk = jax.device_get((_latest[0].per_chunk, _latest[1]))
+    chunk = int(chunk)
+    history = next(iter(rings.values())).shape[-1]
+    if not 1 <= k <= min(chunk, history):
+        raise ValueError(f"last {k} chunks asked of a state after {chunk} "
+                         f"chunk(s) with a ring of {history}")
+    slots = [(chunk - k + i) % history for i in range(k)]
+    return {key: np.asarray(r, np.float64).reshape(-1, history)
+            .sum(axis=0)[slots] for key, r in rings.items()}

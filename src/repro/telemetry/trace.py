@@ -3,10 +3,18 @@
 ``span(name)`` is a context manager that (a) records a wall-clock span
 (start, duration, nesting depth, parent) into a process-wide ring and
 (b) opens a ``jax.profiler.TraceAnnotation`` so the same region shows up
-as a named slice in a captured Perfetto/XPlane trace. The Simulator wraps
+as a named slice in a captured Perfetto/XPlane trace. With ``step_num``
+it opens a ``jax.profiler.StepTraceAnnotation`` instead, which the
+profiler shows as a step marker. Each record keeps its start and end in
+ns on ``time.time_ns()``, the wall clock the profiler stamps host events
+with, so a span read from ``spans()`` can be set directly against the
+device ops and idle gaps of a captured trace. The Simulator wraps
 ``from_config`` / ``init`` / ``step`` / ``run`` / ``lower`` / ``save`` /
-``restore`` in spans; phase-level device-side annotation uses
-``jax.named_scope`` inside the traced chunk (sim/phases.py).
+``restore`` in spans; ``run``, ``step`` and ``step_with`` open theirs as
+step markers numbered by the first chunk they advance. Phase-level
+device-side annotation uses ``jax.named_scope`` inside the traced chunk
+(sim/phases.py, and the ``repro.bh.*``, ``repro.conn.accept`` and
+``repro.act.input`` scopes within phase B and the activity step).
 
 ``profile(log_dir)`` guards ``jax.profiler.trace``: a failure to start
 (no backend support, a trace already active) degrades to a no-op with a
@@ -40,11 +48,16 @@ class Span:
     depth: int = 0
     parent: Optional[str] = None
     attrs: Dict[str, Any] = field(default_factory=dict)
+    start_ns: int = 0           # time.time_ns() at entry: the profiler's clock
+    end_ns: int = 0             # time.time_ns() at exit; 0 while still open
+    step_num: Optional[int] = None   # set on a step marker
 
     def asdict(self) -> dict:
         return {"name": self.name, "start_s": self.start_s,
                 "duration_ms": self.duration_ms, "depth": self.depth,
-                "parent": self.parent, "attrs": dict(self.attrs)}
+                "parent": self.parent, "attrs": dict(self.attrs),
+                "start_ns": self.start_ns, "end_ns": self.end_ns,
+                "step_num": self.step_num}
 
 
 def _stack() -> List[Span]:
@@ -54,17 +67,22 @@ def _stack() -> List[Span]:
 
 
 @contextlib.contextmanager
-def span(name: str, **attrs):
-    """Record a named wall-clock span (and a profiler TraceAnnotation).
-    Yields the Span record; callers may add ``attrs`` to it."""
+def span(name: str, step_num: Optional[int] = None, **attrs):
+    """Record a named wall-clock span (and a profiler TraceAnnotation, or
+    with ``step_num`` a StepTraceAnnotation: a step marker). Yields the
+    Span record; callers may add ``attrs`` to it."""
     stack = _stack()
     rec = Span(name=name, start_s=time.perf_counter(), depth=len(stack),
-               parent=stack[-1].name if stack else None, attrs=dict(attrs))
+               parent=stack[-1].name if stack else None, attrs=dict(attrs),
+               start_ns=time.time_ns(), step_num=step_num)
     stack.append(rec)
+    annotation = jax.profiler.TraceAnnotation(name) if step_num is None \
+        else jax.profiler.StepTraceAnnotation(name, step_num=int(step_num))
     try:
-        with jax.profiler.TraceAnnotation(name):
+        with annotation:
             yield rec
     finally:
+        rec.end_ns = time.time_ns()
         stack.pop()
         rec.duration_ms = (time.perf_counter() - rec.start_s) * 1e3
         with _records_lock:

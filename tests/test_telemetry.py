@@ -4,10 +4,12 @@ mesh via subprocess), span nesting, report schema round-trip /
 normalization of the pre-schema layouts, and the regression gate's rule
 taxonomy on synthetic baselines."""
 import dataclasses
+import glob
 import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +34,10 @@ EXCHANGE_LAYOUT_KEYS = ("rates_sent", "subscription_requests",
                         "subscription_overflow", "request_overflow")
 PHYSICS_KEYS = tuple(k for k in tm.COUNTER_KEYS
                      if k not in EXCHANGE_LAYOUT_KEYS)
+# phase B's work and waste: query rows run, rows holding a request,
+# restart iterations run over all rows, live rows whose frontier overflowed
+PHASE_B_KEYS = ("bh_query_slots", "bh_queries_live", "bh_rounds_run",
+                "bh_frontier_overflow")
 
 
 # ---------------------------------------------------------------- metrics
@@ -96,6 +102,9 @@ def test_metrics_pytree_roundtrip_with_stable_keys():
     names = {"/".join(str(k.key) for k in path) for path, _ in kl}
     assert "counters/rates_sent" in names
     assert "hists/frontier_depth" in names
+    for key in PHASE_B_KEYS:
+        assert f"counters/{key}" in names
+        assert f"per_chunk/{key}" in names
 
 
 # ---------------------------------------------------------------- identity
@@ -141,6 +150,98 @@ def test_counters_bit_identical_reference_vs_fused_connectivity():
     hb = np.asarray(b.metrics().hists["frontier_depth"])
     np.testing.assert_array_equal(ha, hb)
     assert ca["bh_restarts"].sum() > 0, "traversal depth never recorded"
+    # phase B's work and waste, per chunk too
+    for k in PHASE_B_KEYS:
+        np.testing.assert_array_equal(
+            np.asarray(a.metrics().per_chunk[k]),
+            np.asarray(b.metrics().per_chunk[k]), err_msg=k)
+    assert ca["bh_queries_live"].sum() > 0
+    assert ca["bh_frontier_overflow"].sum() <= ca["bh_queries_live"].sum()
+    assert ca["bh_restarts"].sum() <= ca["bh_rounds_run"].sum()
+
+
+def _overflow_tree(frontier):
+    """One neuron at the centre of each of the 64 deepest cells of a
+    two-level subtree: every cell holds a neuron, and at theta 0.3 every
+    child of the root fails the acceptance criterion, so each query wants
+    all 64 deepest cells in its frontier."""
+    from repro.connectome import tree as ctree
+    cfg = BrainConfig(neurons_per_rank=64, local_levels=2,
+                      frontier_cap=frontier, max_synapses=8, theta=0.3)
+    g = (np.arange(4) + 0.5) / 4
+    pos = jnp.asarray(np.stack(np.meshgrid(g, g, g, indexing="ij"),
+                               -1).reshape(-1, 3), jnp.float32)
+    vac = jnp.ones((64,), jnp.float32)
+    return cfg, ctree.build_local_tree(pos, vac, 0, cfg, num_ranks=1), pos, \
+        vac
+
+
+@pytest.mark.parametrize("frontier,overflows", [(8, True), (64, False)])
+def test_frontier_overflow_counter_on_hand_built_tree(frontier, overflows):
+    """A frontier of 8 cannot hold the root's expanded children: every live
+    query overflows. A frontier of 64 holds the whole subtree: none does.
+    Rows without a request are never counted."""
+    from repro.connectome import traverse
+    cfg, tree, pos, vac = _overflow_tree(frontier)
+    stacked = traverse.stack_levels(tree.counts, tree.centroids, 0)
+    live = jnp.arange(64) % 4 != 0
+    _, _, depth, overflow = traverse.phase_b_core(
+        stacked.counts, stacked.centroids, tree.leaf_members, pos, vac, pos,
+        jnp.zeros((64,), jnp.int32), jnp.arange(64, dtype=jnp.int32), live,
+        jnp.int32(1), jnp.int32(0), seed=cfg.seed, sizes=stacked.sizes,
+        theta=cfg.theta, sigma=cfg.sigma, frontier=cfg.frontier_cap,
+        n_levels=traverse.phase_b_levels(cfg))
+    m = tm.Recorder(n=64).traversal(tm.init_metrics(), depth, live, overflow,
+                                    traverse.phase_b_levels(cfg))
+    counted = {k: float(m.counters[k][0]) for k in PHASE_B_KEYS}
+    assert counted["bh_queries_live"] == 48
+    assert counted["bh_query_slots"] == 64
+    assert counted["bh_rounds_run"] == 64 * 3
+    assert counted["bh_frontier_overflow"] == (48 if overflows else 0)
+
+
+def test_phase_b_slots_and_live_rows_per_chunk():
+    """Per chunk, phase B runs R * cap query rows (the new algorithm's
+    receive buffer) and counts as live exactly the requests it received
+    (all of them: nothing overflows at this cap)."""
+    from repro.connectome import routing, traverse
+    sim = _run(SMALL)
+    r = sim.num_ranks
+    ring = {k: np.asarray(v).sum(axis=0)[:2]
+            for k, v in sim.metrics().per_chunk.items()}
+    cap = routing.cap_requests(SMALL, r)
+    np.testing.assert_array_equal(ring["bh_query_slots"], [r * r * cap] * 2)
+    np.testing.assert_array_equal(ring["bh_rounds_run"],
+                                  [r * r * cap
+                                   * traverse.phase_b_levels(SMALL)] * 2)
+    assert not ring["request_overflow"].any()
+    np.testing.assert_array_equal(ring["bh_queries_live"],
+                                  ring["formation_requests"])
+    assert ring["bh_queries_live"].sum() > 0
+
+
+def test_last_chunk_counters_reads_the_ring_of_the_latest_state(
+        monkeypatch):
+    monkeypatch.setattr(tm, "_latest", None)
+    assert telemetry.last_chunk_counters(1) is None
+    sim = Simulator(SMALL)
+    sim.run(2)
+    before = {k: np.asarray(v).sum() for k, v in sim.stats(
+        reduce=False).items()}
+    sim.run(1)
+    sim.step()
+    after = sim.metrics()
+    got = telemetry.last_chunk_counters(2)
+    hist = after.per_chunk["bh_queries_live"].shape[-1]
+    for k in tm.COUNTER_KEYS:
+        ring = np.asarray(after.per_chunk[k]).sum(axis=0)
+        np.testing.assert_array_equal(got[k], ring[[2 % hist, 3 % hist]],
+                                      err_msg=k)
+        np.testing.assert_allclose(
+            got[k].sum(), np.asarray(after.counters[k]).sum() - before[k],
+            err_msg=k)
+    with pytest.raises(ValueError):
+        telemetry.last_chunk_counters(5)
 
 
 def test_physics_counters_identical_dense_vs_sparse():
@@ -239,6 +340,44 @@ def test_simulator_records_spans():
         assert expected in names, names
     run_span = telemetry.spans("sim.run")[-1]
     assert run_span.attrs.get("chunks") == 1
+
+
+def test_span_times_on_the_profilers_clock():
+    before = time.time_ns()
+    with telemetry.span("clock") as rec:
+        inside = time.time_ns()
+    after = time.time_ns()
+    assert before <= rec.start_ns <= inside <= rec.end_ns <= after
+    assert telemetry.export()[-1]["start_ns"] == rec.start_ns
+
+
+def test_run_opens_step_markers_numbered_by_the_host_chunk_count(
+        tmp_path):
+    """Each ``run`` is a StepTraceAnnotation in a captured trace, numbered
+    by the chunks run before it, and its host event starts within 1 ms of
+    the span's in-memory start."""
+    from jax.profiler import ProfileData
+    telemetry.clear()
+    sim = Simulator(SMALL)
+    sim.run(2)                       # compiled outside the capture
+    sim.run(1)
+    jax.profiler.start_trace(str(tmp_path))
+    sim.run(1)
+    sim.run(1)
+    jax.profiler.stop_trace()
+    spans = telemetry.spans("sim.run")
+    assert [s.step_num for s in spans] == [0, 2, 3, 4]
+    assert sim.host_chunk == 5 == int(sim.state.chunk)
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path[-1])
+    t0 = [int(dict(p.stats)["profile_start_time"]) for p in pd.planes
+          if "profile_start_time" in dict(p.stats)][0]
+    marks = {int(dict(ev.stats)["step_num"]): t0 + ev.start_ns
+             for p in pd.planes for line in p.lines for ev in line.events
+             if ev.name == "sim.run" and "step_num" in dict(ev.stats)}
+    assert sorted(marks) == [3, 4]
+    for s in spans[-2:]:
+        assert abs(marks[s.step_num] - s.start_ns) < 1e6
 
 
 def test_profile_none_is_noop():
