@@ -7,8 +7,9 @@ so the whole phase lives here, out of the engine:
 
   tree.py      level-array octree (rank-local subtree + replicated top tree)
   traverse.py  vectorized Barnes-Hut search — phase A over the top tree,
-               phase B over one subtree; ``phase_b_core`` is the shared jnp
-               math executed by both the reference path and the Pallas
+               phase B over one subtree; ``expand_and_sample`` and the
+               member selection are the shared jnp math of the reference
+               path (restarts over the searching rows only) and the Pallas
                traversal kernel (kernels/bh_traverse.py), bit-identical
   synapses.py  synapse-table ops (counts/compact/accept/retract/remove),
                all vectorized segment/cumsum — no sequential loops

@@ -153,9 +153,10 @@ def formation_new(cfg, positions, local_tree, vacant_d, in_edges, gids,
                   num_ranks: int, key, chunk):
     """Location-aware algorithm: 42B requests out, local phase B + accept,
     9B responses back. Returns (tgt_gid, accept dict, overflow count,
-    (depth, processed, frontier_overflow)) — the per-received-request
-    phase-B restart depth, its validity mask and the frontier overflow
-    flags, recorded into the telemetry by the caller."""
+    (depth, processed, frontier_overflow, rows_run)) — the
+    per-received-request phase-B restart depth, its validity mask, the
+    frontier overflow flags and the row-restarts phase B computed, recorded
+    into the telemetry by the caller."""
     n = cfg.neurons_per_rank
     cap = cap_requests(cfg, num_ranks)
     dest = jnp.where(valid_a, owner, num_ranks)
@@ -180,7 +181,7 @@ def formation_new(cfg, positions, local_tree, vacant_d, in_edges, gids,
     r_valid = r_src >= 0
     # the receiver re-derives the SAME per-searcher Gumbel stream from the
     # shipped source gid (counter-hash keyed by (chunk, gid) — DESIGN.md §2)
-    tgt, bvalid, depth, frontier_ovf = traverse.phase_b(
+    tgt, bvalid, depth, frontier_ovf, rows_run = traverse.phase_b(
         local_tree, positions, vacant_d, r_pos,
         jnp.where(r_valid, r_src, -2), jnp.clip(r_cell, 0, None), r_valid,
         cfg, num_ranks, rank * n, chunk=chunk)
@@ -199,7 +200,7 @@ def formation_new(cfg, positions, local_tree, vacant_d, in_edges, gids,
     resp_tgt = rbuf[d_c, s_c, 0]
     resp_ok = (rbuf[d_c, s_c, 1] > 0) & ok
     return resp_tgt, {"accepted": resp_ok, "in_edges": new_in}, ovf, \
-        (depth, r_valid, frontier_ovf)
+        (depth, r_valid, frontier_ovf, rows_run)
 
 
 def formation_old(cfg, positions, local_tree, vacant_d, in_edges, gids,
@@ -208,9 +209,9 @@ def formation_old(cfg, positions, local_tree, vacant_d, in_edges, gids,
     """Baseline: download every rank's subtree + leaf data (RMA+cache
     endpoint), search locally, then exchange 17B formation requests.
     Returns (tgt_gid, accepted, new_in_edges, downloaded node count,
-    (depth, searched, frontier_overflow)) — the per-local-searcher phase-B
-    restart depth, its mask and the frontier overflow flags, for the
-    telemetry."""
+    (depth, searched, frontier_overflow, rows_run)) — the per-local-searcher
+    phase-B restart depth, its mask, the frontier overflow flags and the
+    row-restarts phase B computed, for the telemetry."""
     n = cfg.neurons_per_rank
     # ---- the download: all levels, members, positions, weights ----
     if num_ranks > 1:
@@ -233,7 +234,7 @@ def formation_old(cfg, positions, local_tree, vacant_d, in_edges, gids,
     g_tree = ctree.LocalTree(g_counts, g_cents, g_members,
                              jnp.zeros((), jnp.int32))
     # ---- phase B locally for my searchers (same PRNG stream as 'new') ----
-    tgt, bvalid, depth, frontier_ovf = traverse.phase_b(
+    tgt, bvalid, depth, frontier_ovf, rows_run = traverse.phase_b(
         g_tree, g_pos, g_vac, positions, gids, branch_cell, valid_a, cfg,
         num_ranks, 0, chunk=chunk)
     # ---- classic 17B formation request to the target's rank ----
@@ -262,4 +263,4 @@ def formation_old(cfg, positions, local_tree, vacant_d, in_edges, gids,
         rbuf = jax.lax.all_to_all(rbuf, axis_name, 0, 0, tiled=True)
     accepted = (rbuf[d_c, s_c] > 0) & ok
     return tgt, accepted, new_in, jnp.asarray(downloaded, jnp.float32), \
-        (depth, valid_a, frontier_ovf)
+        (depth, valid_a, frontier_ovf, rows_run)

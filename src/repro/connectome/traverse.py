@@ -9,12 +9,18 @@ selects the target; sampling an inner node restarts the expansion from it.
 
 Static-shape deviations (documented in DESIGN.md §2/§6): the frontier is
 capped at F entries — parents whose children would overflow are kept as
-sampling candidates at coarser granularity; ``phase_b_core`` returns each
+sampling candidates at coarser granularity; phase B returns each
 query's overflow flag, and the telemetry counts the live ones
 (``bh_frontier_overflow``).
 
+Two restart drivers run the same ``expand_and_sample``: ``bh_search`` runs
+every restart over every row (the Pallas kernel body and phase A);
+``bh_search_packed``, phase B's reference lowering, runs each restart only
+over the rows still searching, in row blocks of ``PACK_ROWS``.
+
 Named scopes mark the stages for the profiler: ``repro.bh.search`` (the
-restart loop), ``repro.bh.expand`` (frontier set-up and expansion rounds),
+restart loop, with the packed driver's row partition and block loop),
+``repro.bh.expand`` (frontier set-up and expansion rounds),
 ``repro.bh.sample`` (the Gumbel pick) and ``repro.bh.member`` (member
 selection). They change only HLO metadata.
 
@@ -49,6 +55,11 @@ PAD = 8   # coordinate lanes (3 -> 8), the bh_gauss MXU alignment
 PHASE_A_ROUND_BASE = 0
 PHASE_B_ROUND_BASE = 16
 MEMBER_ROUND = chash.BH_ROUNDS - 1
+# query rows per block of the reference lowering's packed restart driver
+# (``bh_search_packed``); Q below it runs as one block. Of 4,096, 8,192 and
+# 16,384, 4,096 ran phase B fastest at 65,536 queries on a TPU v5e: the
+# last block of each restart pads less
+PACK_ROWS = 4096
 
 
 class StackedTree(NamedTuple):
@@ -235,11 +246,35 @@ def _expand(tree: StackedTree, x, root_cell, root_rel, *, sigma, theta,
     return state
 
 
+def _settle(st, new, last: int):
+    """One restart's carry update: rows already ``done`` keep their result,
+    the others take ``new`` (``expand_and_sample``'s outputs) and count the
+    restart; a row is done once it holds a deepest-level cell or found no
+    candidate."""
+    cell, rel, valid, done, overflow, depth = st
+    ncell, nrel, nvalid, noverf = new
+    cell = jnp.where(done, cell, ncell)
+    rel = jnp.where(done, rel, nrel)
+    valid = jnp.where(done, valid, nvalid)
+    overflow = overflow | jnp.where(done, False, noverf)
+    depth = depth + jnp.where(done, 0, 1).astype(jnp.int32)
+    done = done | (rel >= last) | ~valid
+    return (cell, rel, valid, done, overflow, depth)
+
+
 def bh_search(tree: StackedTree, x, src_gid, start_cell, *, seed: int, chunk,
               theta, sigma, frontier, n_levels, round_base=0,
               max_restarts=None):
     """Full search: expand/sample, restarting inside sampled inner nodes until
     a deepest-level cell is returned (paper's 'process restarts' loop).
+
+    A restart that does not finish moves its row at least one level down:
+    the frontier starts at the restart node's children, so the sampled node
+    lies below it. No row therefore needs more than ``n_levels - 1``
+    restarts, and the cap's last iteration finds every row done. This
+    driver runs every restart over every row, as a kernel body must (the
+    Pallas traversal kernel, phase A); ``bh_search_packed`` runs only the
+    rows still searching.
 
     x: (Q,3); src_gid: (Q,) searcher gids (PRNG entities); start_cell: (Q,)
     cell at tree level 0. Returns (leaf_cell (Q,), valid (Q,), overflow (Q,),
@@ -253,19 +288,11 @@ def bh_search(tree: StackedTree, x, src_gid, start_cell, *, seed: int, chunk,
     _check_caps(frontier, round_base, restarts)
 
     def body(i, st):
-        cell, rel, valid, done, overflow, depth = st
-        ncell, nrel, nvalid, noverf = expand_and_sample(
-            tree, x, cell, rel, src_gid, round_base + i, seed=seed,
+        new = expand_and_sample(
+            tree, x, st[0], st[1], src_gid, round_base + i, seed=seed,
             chunk=chunk, theta=theta, sigma=sigma, frontier=frontier,
             n_levels=n_levels)
-        # keep previous result where already done
-        cell = jnp.where(done, cell, ncell)
-        rel = jnp.where(done, rel, nrel)
-        valid = jnp.where(done, valid, nvalid)
-        overflow = overflow | jnp.where(done, False, noverf)
-        depth = depth + jnp.where(done, 0, 1).astype(jnp.int32)
-        done = done | (rel >= last) | ~valid
-        return (cell, rel, valid, done, overflow, depth)
+        return _settle(st, new, last)
 
     with jax.named_scope("repro.bh.search"):
         st = (start_cell.astype(jnp.int32), jnp.zeros((q,), jnp.int32),
@@ -275,6 +302,74 @@ def bh_search(tree: StackedTree, x, src_gid, start_cell, *, seed: int, chunk,
             0, restarts, body, st)
         valid = valid & (rel >= last)
     return cell, valid, overflow, depth
+
+
+def bh_search_packed(tree: StackedTree, x, src_gid, start_cell, live, *,
+                     seed: int, chunk, theta, sigma, frontier, n_levels,
+                     round_base=0, block=None):
+    """``bh_search`` that runs each restart only over the rows still
+    searching. Rows with ``live`` false start done. At each restart a stable
+    partition puts the searching rows first; ``expand_and_sample`` runs over
+    them in row blocks of ``block`` (default ``PACK_ROWS``, at most Q, Q
+    padded up to a multiple of it with done rows), and the block results go
+    back to their own rows. Every op of ``expand_and_sample`` is
+    row-independent and its Gumbel stream is keyed by the source gid, not
+    the row, so each live row gets ``bh_search``'s result bit for bit. A
+    restart with no searching row runs no block.
+
+    Returns ``bh_search``'s four outputs (depth 0 and overflow false on rows
+    not ``live``) and the row-restarts computed, block padding included
+    (i32 scalar)."""
+    q = x.shape[0]
+    b = min(q, block or PACK_ROWS)
+    qp = -(-q // b) * b
+    last = n_levels - 1
+    _check_caps(frontier, round_base, n_levels)
+
+    def pad(a):
+        return jnp.pad(a, [(0, qp - q)] + [(0, 0)] * (a.ndim - 1))
+
+    x, src_gid, start_cell, live = map(pad, (x, src_gid, start_cell, live))
+
+    def restart(i, carry):
+        st, rows_run = carry
+        cell, rel, done = st[0], st[1], st[3]
+        order = jnp.argsort(done, stable=True)        # searching rows first
+        searching = ~done
+        n_active = jnp.sum(searching.astype(jnp.int32))
+        # packed position of each row under ``order``
+        packed_at = jnp.where(
+            searching, jnp.cumsum(searching) - 1,
+            n_active + jnp.cumsum(done) - 1)
+        n_blocks = (n_active + b - 1) // b
+
+        def block_body(c):
+            k, out = c
+            rows = jax.lax.dynamic_slice(order, (k * b,), (b,))
+            new = expand_and_sample(
+                tree, x[rows], cell[rows], rel[rows], src_gid[rows],
+                round_base + i, seed=seed, chunk=chunk, theta=theta,
+                sigma=sigma, frontier=frontier, n_levels=n_levels)
+            out = tuple(jax.lax.dynamic_update_slice(o, v, (k * b,))
+                        for o, v in zip(out, new))
+            return k + 1, out
+
+        out = (jnp.zeros((qp,), jnp.int32), jnp.zeros((qp,), jnp.int32),
+               jnp.zeros((qp,), bool), jnp.zeros((qp,), bool))
+        _, out = jax.lax.while_loop(lambda c: c[0] < n_blocks, block_body,
+                                    (jnp.int32(0), out))
+        new = tuple(o[packed_at] for o in out)
+        return _settle(st, new, last), rows_run + n_blocks * b
+
+    with jax.named_scope("repro.bh.search"):
+        st = (start_cell.astype(jnp.int32), jnp.zeros((qp,), jnp.int32),
+              jnp.ones((qp,), bool), ~live, jnp.zeros((qp,), bool),
+              jnp.zeros((qp,), jnp.int32))
+        st, rows_run = jax.lax.fori_loop(0, n_levels, restart,
+                                         (st, jnp.int32(0)))
+        cell, rel, valid, _, overflow, depth = (a[:q] for a in st)
+        valid = valid & (rel >= last)
+    return cell, valid, overflow, depth, rows_run
 
 
 def select_member(x, member_pos, member_weight, member_valid, src_gid, *,
@@ -320,12 +415,13 @@ def phase_b_core(counts, cents, leaf_members, neuron_pos, vacant_d, x,
                  start_cell_rel, src_gid, valid_in, chunk, gid_base, *,
                  seed: int, sizes, theta: float, sigma: float, frontier: int,
                  n_levels: int):
-    """Finish the search inside one rank's subtree, raw stacked arrays — the
-    single source of truth executed by the Pallas traversal kernel body
-    (kernels/bh_traverse.py) and the jnp reference path, which is what makes
-    ``connectivity_impl='fused'`` bit-identical to ``'reference'``. Every
-    operation is row-independent over Q, so the kernel's query blocking
-    cannot change results.
+    """Finish the search inside one rank's subtree, raw stacked arrays: the
+    body of the Pallas traversal kernel (kernels/bh_traverse.py), which
+    runs ``bh_search`` over every row of its query block. Every operation
+    is row-independent over Q, so the kernel's query blocking cannot change
+    results, and the reference lowering (``phase_b_reference``: the same
+    ``expand_and_sample`` through ``bh_search_packed``, then the same
+    ``phase_b_members``) gives the same answers.
 
     counts: (L, C); cents: (L, C, 3) centroids (``stack_levels``); sizes:
     static tuple of per-level cell edge lengths; leaf_members: (n_leaf,
@@ -339,7 +435,16 @@ def phase_b_core(counts, cents, leaf_members, neuron_pos, vacant_d, x,
         tree, x, src_gid, start_cell_rel, seed=seed, chunk=chunk, theta=theta,
         sigma=sigma, frontier=frontier, n_levels=n_levels,
         round_base=PHASE_B_ROUND_BASE)
-    valid = valid & valid_in
+    tgt, ok = phase_b_members(leaf_members, neuron_pos, vacant_d, x, src_gid,
+                              leaf_cell, valid & valid_in, chunk, gid_base,
+                              seed=seed, sigma=sigma)
+    return tgt, ok, depth, overflow
+
+
+def phase_b_members(leaf_members, neuron_pos, vacant_d, x, src_gid,
+                    leaf_cell, valid, chunk, gid_base, *, seed: int, sigma):
+    """Phase B's member selection in the leaf cell each query settled on.
+    Returns (target_gid (Q,), -1 where not ok; ok (Q,))."""
     with jax.named_scope("repro.bh.member"):
         members = leaf_members[leaf_cell]              # (Q, M) local ids
         mvalid = members >= 0
@@ -354,18 +459,25 @@ def phase_b_core(counts, cents, leaf_members, neuron_pos, vacant_d, x,
         tgt_local = jnp.take_along_axis(msafe, pick[:, None], axis=1)[:, 0]
         tgt_gid = gid_base + tgt_local
         ok = valid & pvalid
-        return jnp.where(ok, tgt_gid, -1), ok, depth, overflow
+        return jnp.where(ok, tgt_gid, -1), ok
 
 
 @registry.register_phase("traversal", "reference")
 def phase_b_reference(stacked, local, neuron_pos, vacant_d, pos,
                       start_cell_rel, src_gid, valid_in, chunk, gid_base,
-                      kw, interpret=None):
-    """The jnp ``phase_b_core`` over the full query batch."""
-    return phase_b_core(stacked.counts, stacked.centroids,
-                        local.leaf_members, neuron_pos, vacant_d, pos,
-                        start_cell_rel, src_gid, valid_in, chunk, gid_base,
-                        **kw)
+                      kw, interpret=None, block=None):
+    """jnp phase B: ``bh_search_packed`` over the query rows that hold a
+    request, then ``phase_b_members``. Returns ``phase_b_core``'s outputs
+    and the row-restarts computed. ``block`` overrides ``PACK_ROWS``."""
+    leaf_cell, valid, overflow, depth, rows_run = bh_search_packed(
+        stacked, pos, src_gid, start_cell_rel, valid_in, seed=kw["seed"],
+        chunk=chunk, theta=kw["theta"], sigma=kw["sigma"],
+        frontier=kw["frontier"], n_levels=kw["n_levels"],
+        round_base=PHASE_B_ROUND_BASE, block=block)
+    tgt, ok = phase_b_members(local.leaf_members, neuron_pos, vacant_d, pos,
+                              src_gid, leaf_cell, valid & valid_in, chunk,
+                              gid_base, seed=kw["seed"], sigma=kw["sigma"])
+    return tgt, ok, depth, overflow, rows_run
 
 
 @registry.register_phase("traversal", "fused")
@@ -373,18 +485,21 @@ def phase_b_fused(stacked, local, neuron_pos, vacant_d, pos,
                   start_cell_rel, src_gid, valid_in, chunk, gid_base, kw,
                   interpret=None):
     """The Pallas traversal kernel (kernels/bh_traverse.py), query-blocked,
-    same core math — bit-identical to the reference."""
+    same core math — bit-identical to the reference. Every row runs every
+    restart: the row-restarts computed are Q x ``n_levels``."""
     from repro.kernels import ops as kops   # lazy: kernels import us
-    return kops.bh_traverse(
+    out = kops.bh_traverse(
         stacked.counts, stacked.centroids, local.leaf_members,
         neuron_pos, vacant_d, pos, start_cell_rel, src_gid, valid_in,
         chunk, gid_base, interpret=interpret, **kw)
+    return (*out, jnp.int32(pos.shape[0] * kw["n_levels"]))
 
 
 def phase_b_levels(cfg) -> int:
-    """Levels of the subtree phase B searches, its branch node included.
-    Phase B gives ``bh_search`` no ``max_restarts``, so this is also the
-    number of restart iterations it runs over every query row."""
+    """Levels of the subtree phase B searches, its branch node included,
+    and its restart cap: the fused kernel runs that many restart iterations
+    over every query row; the reference runs each over the rows still
+    searching only (``bh_search_packed``), and its last over none."""
     return cfg.local_levels + 1
 
 
@@ -393,7 +508,8 @@ def phase_b(local, neuron_pos, vacant_d, pos, src_gid, start_cell_rel,
             interpret=None):
     """Phase-B dispatch per ``cfg.connectivity_impl`` (phase-registry
     domain "traversal"): 'reference' vs 'fused' — bit-identical lowerings
-    of the same core math.
+    of the same core math. Returns (target_gid, ok, depth, overflow): (Q,)
+    each, and the row-restarts computed (i32 scalar).
 
     local: a tree.LocalTree (or the gathered global tree in the old
     algorithm, with gid_base = 0 and global leaf members)."""

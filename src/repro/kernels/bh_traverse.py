@@ -8,15 +8,17 @@ neuron data — VMEM-resident while a block of queries runs the full restart
 loop, instead of re-streaming (Q, F) frontier temporaries through HBM every
 expansion round like the reference lowering does.
 
-The kernel body executes ``repro.connectome.traverse.phase_b_core`` — the
-same jnp math as the reference path, including the ``bh_gauss`` MXU distance
-identity (|x|^2+|y|^2-2<x,y> over 8 zero-padded lanes) for node and member
-probabilities, and the counter-based Threefry Gumbel stream keyed by
-``(seed, chunk, source_gid, round, draw)`` (kernels/hash.py). Every op is
-row-independent over queries, so blocking cannot change results:
-``connectivity_impl='fused'`` is bit-identical to ``'reference'``
-(tests/test_connectome.py). Like the other kernels here, CPU containers run
-it with ``interpret=True``.
+The kernel body executes ``repro.connectome.traverse.phase_b_core``, whose
+restart driver runs every row of the block through the restart cap; the
+reference path runs the same ``expand_and_sample`` over the rows still
+searching only, then the same member selection. Both share the ``bh_gauss``
+MXU distance identity (|x|^2+|y|^2-2<x,y> over 8 zero-padded lanes) for
+node and member probabilities, and the counter-based Threefry Gumbel
+stream keyed by ``(seed, chunk, source_gid, round, draw)``
+(kernels/hash.py). Every op is row-independent over queries, so blocking
+cannot change results: ``connectivity_impl='fused'`` is bit-identical to
+``'reference'`` (tests/test_connectome.py). Like the other kernels here,
+CPU containers run it with ``interpret=True``.
 """
 from __future__ import annotations
 

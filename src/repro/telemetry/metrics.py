@@ -59,7 +59,7 @@ LEGACY_KEYS = ("spikes_sent", "rates_sent", "subscription_requests",
 # ... plus the per-phase work counters added with the telemetry layer
 EXTRA_KEYS = ("activity_steps", "activity_spikes", "tree_nodes_built",
               "bh_restarts", "bh_query_slots", "bh_queries_live",
-              "bh_rounds_run", "bh_frontier_overflow")
+              "bh_rounds_run", "bh_frontier_overflow", "bh_rows_run")
 COUNTER_KEYS = LEGACY_KEYS + EXTRA_KEYS
 
 # counter -> phase of the three-phase loop it instruments; the report
@@ -71,7 +71,8 @@ PHASE_OF = {
     "bh_requests": "phase_b", "bh_responses": "phase_b",
     "bh_restarts": "phase_b", "bh_query_slots": "phase_b",
     "bh_queries_live": "phase_b", "bh_rounds_run": "phase_b",
-    "bh_frontier_overflow": "phase_b", "formation_requests": "phase_b",
+    "bh_frontier_overflow": "phase_b", "bh_rows_run": "phase_b",
+    "formation_requests": "phase_b",
     "request_overflow": "phase_b",
     "synapses_formed": "synapse_update", "synapses_deleted": "synapse_update",
     "rates_sent": "exchange", "subscription_requests": "exchange",
@@ -237,21 +238,23 @@ class Recorder:
                     for c in local_tree.counts)
         return m.count("tree_nodes_built", built)
 
-    def traversal(self, m: Metrics, depth, mask, overflow,
+    def traversal(self, m: Metrics, depth, mask, overflow, rows_run,
                   restarts: int) -> Metrics:
         """Record one phase B over its (Q,) query rows, ``mask`` the rows
         holding a request: ``bh_query_slots`` (Q), ``bh_queries_live``,
-        ``bh_rounds_run`` (Q x the ``restarts`` iterations the restart loop
-        runs over every row), ``bh_restarts`` (the iterations live rows
-        needed), ``bh_frontier_overflow`` (live rows whose frontier
-        overflowed) and the frontier-depth histogram. Depths and overflow
-        flags come out of ``bh_search`` identically under both traversal
-        lowerings."""
+        ``bh_rounds_run`` (Q x the ``restarts`` cap), ``bh_restarts`` (the
+        iterations live rows needed), ``bh_rows_run`` (``rows_run``, the
+        row-restarts the traversal lowering computed: Q x ``restarts`` in
+        the fused kernel, the searching rows in whole row blocks in the
+        reference), ``bh_frontier_overflow`` (live rows whose frontier
+        overflowed) and the frontier-depth histogram. On the live rows,
+        depths and overflow flags are identical under both lowerings."""
         w = mask.astype(jnp.float32)
         slots = mask.shape[0]
         m = m.count("bh_query_slots", jnp.float32(slots))
         m = m.count("bh_queries_live", jnp.sum(w))
         m = m.count("bh_rounds_run", jnp.float32(slots * restarts))
+        m = m.count("bh_rows_run", jnp.asarray(rows_run, jnp.float32))
         m = m.count("bh_restarts", jnp.sum(depth.astype(jnp.float32) * w))
         m = m.count("bh_frontier_overflow",
                     jnp.sum(overflow.astype(jnp.float32) * w))

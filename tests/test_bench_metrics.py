@@ -37,7 +37,7 @@ TIME_READERS = {
     "activity.input.device_ms": "repro.activity/repro.act.input",
 }
 SHARE_READERS = ("conn.formation.live_pct", "conn.formation.round_use_pct",
-                 "conn.formation.overflow_pct")
+                 "conn.formation.overflow_pct", "conn.formation.rows_run_pct")
 NEW_READERS = tuple(TIME_READERS) + SHARE_READERS + ("host.dispatch_ms",)
 
 

@@ -169,6 +169,50 @@ def test_bh_traverse_prng_is_location_independent():
     np.testing.assert_array_equal(np.asarray(a[1])[perm], np.asarray(b[1]))
 
 
+@pytest.mark.parametrize("live,q,block", [
+    ("all", 80, 16),
+    ("every_fifth_masked", 80, 16),
+    ("none", 80, 16),
+    ("every_fifth_masked", 75, 16),     # Q not a multiple of the block
+    ("every_fifth_masked", 75, 128),    # Q below the block
+])
+def test_packed_phase_b_reference_equals_phase_b_core(live, q, block):
+    """The reference lowering runs each restart only over the rows still
+    searching, packed into row blocks; it gives ``phase_b_core``'s targets
+    on every row, and its depths and overflow flags on the live rows, bit
+    for bit, and counts whole blocks."""
+    cfg, tree, pos, vac, x, gids, start, valid = _phase_b_inputs(q=q)
+    valid = {"all": jnp.ones((q,), bool), "none": jnp.zeros((q,), bool),
+             "every_fifth_masked": valid}[live]
+    stacked = traverse.stack_levels(tree.counts, tree.centroids, 0)
+    kw = dict(seed=cfg.seed, sizes=stacked.sizes, theta=cfg.theta,
+              sigma=cfg.sigma, frontier=cfg.frontier_cap,
+              n_levels=traverse.phase_b_levels(cfg))
+    chunk, gid_base = jnp.int32(2), jnp.int32(0)
+    want = jax.jit(lambda: traverse.phase_b_core(
+        stacked.counts, stacked.centroids, tree.leaf_members, pos, vac, x,
+        start, gids, valid, chunk, gid_base, **kw))()
+    got = jax.jit(lambda: traverse.phase_b_reference(
+        stacked, tree, pos, vac, x, start, gids, valid, chunk, gid_base, kw,
+        block=block))()
+    live_rows = np.asarray(valid)
+    for k in (0, 1):                                   # target, ok
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
+    for k in (2, 3):                                   # depth, overflow
+        np.testing.assert_array_equal(np.asarray(got[k])[live_rows],
+                                      np.asarray(want[k])[live_rows])
+    rows_run, b = int(got[4]), min(q, block)
+    assert rows_run % b == 0
+    assert rows_run >= int(np.asarray(want[2])[live_rows].sum())
+    if live == "none":
+        assert rows_run == 0
+    else:
+        assert int(jnp.sum(got[1])) > 0, "no query found a partner at all"
+        assert rows_run < q * kw["n_levels"]
+    if live == "all":
+        assert rows_run > 2 * b, "the search ran fewer than three blocks"
+
+
 def test_connectivity_impl_validation():
     # unknown variant names fail eagerly at config construction
     base = BrainConfig(neurons_per_rank=16, local_levels=2, frontier_cap=32,

@@ -35,9 +35,14 @@ EXCHANGE_LAYOUT_KEYS = ("rates_sent", "subscription_requests",
 PHYSICS_KEYS = tuple(k for k in tm.COUNTER_KEYS
                      if k not in EXCHANGE_LAYOUT_KEYS)
 # phase B's work and waste: query rows run, rows holding a request,
-# restart iterations run over all rows, live rows whose frontier overflowed
+# restart iterations under the cap over all rows, live rows whose frontier
+# overflowed, row-restarts the lowering computed
 PHASE_B_KEYS = ("bh_query_slots", "bh_queries_live", "bh_rounds_run",
-                "bh_frontier_overflow")
+                "bh_frontier_overflow", "bh_rows_run")
+# the one counter that tells the traversal lowerings apart: the fused
+# kernel runs every row through the restart cap, the reference only the
+# rows still searching, in whole row blocks
+LOWERING_KEYS = ("bh_rows_run",)
 
 
 # ---------------------------------------------------------------- metrics
@@ -145,19 +150,26 @@ def test_counters_bit_identical_reference_vs_fused_connectivity():
     b = _run(dataclasses.replace(SMALL, connectivity_impl="fused"))
     ca, cb = _counters(a), _counters(b)
     for k in tm.COUNTER_KEYS:
-        np.testing.assert_array_equal(ca[k], cb[k], err_msg=k)
+        if k not in LOWERING_KEYS:
+            np.testing.assert_array_equal(ca[k], cb[k], err_msg=k)
     ha = np.asarray(a.metrics().hists["frontier_depth"])
     hb = np.asarray(b.metrics().hists["frontier_depth"])
     np.testing.assert_array_equal(ha, hb)
     assert ca["bh_restarts"].sum() > 0, "traversal depth never recorded"
     # phase B's work and waste, per chunk too
     for k in PHASE_B_KEYS:
-        np.testing.assert_array_equal(
-            np.asarray(a.metrics().per_chunk[k]),
-            np.asarray(b.metrics().per_chunk[k]), err_msg=k)
+        if k not in LOWERING_KEYS:
+            np.testing.assert_array_equal(
+                np.asarray(a.metrics().per_chunk[k]),
+                np.asarray(b.metrics().per_chunk[k]), err_msg=k)
     assert ca["bh_queries_live"].sum() > 0
     assert ca["bh_frontier_overflow"].sum() <= ca["bh_queries_live"].sum()
     assert ca["bh_restarts"].sum() <= ca["bh_rounds_run"].sum()
+    # rows computed: every row through the cap in the kernel; the searching
+    # rows, in whole blocks, in the reference
+    np.testing.assert_array_equal(cb["bh_rows_run"], cb["bh_rounds_run"])
+    assert ca["bh_restarts"].sum() <= ca["bh_rows_run"].sum() \
+        <= ca["bh_rounds_run"].sum()
 
 
 def _overflow_tree(frontier):
@@ -176,28 +188,45 @@ def _overflow_tree(frontier):
         vac
 
 
+@pytest.mark.parametrize("block", [None, 16, 64])
 @pytest.mark.parametrize("frontier,overflows", [(8, True), (64, False)])
-def test_frontier_overflow_counter_on_hand_built_tree(frontier, overflows):
+def test_frontier_overflow_counter_on_hand_built_tree(frontier, overflows,
+                                                      block):
     """A frontier of 8 cannot hold the root's expanded children: every live
     query overflows. A frontier of 64 holds the whole subtree: none does.
-    Rows without a request are never counted."""
+    Rows without a request are never counted. ``block`` None runs the
+    plain ``phase_b_core`` (the fused kernel's body) over every row; a
+    block size runs the reference's packed driver, which computes the
+    searching rows in whole blocks only."""
     from repro.connectome import traverse
     cfg, tree, pos, vac = _overflow_tree(frontier)
     stacked = traverse.stack_levels(tree.counts, tree.centroids, 0)
     live = jnp.arange(64) % 4 != 0
-    _, _, depth, overflow = traverse.phase_b_core(
-        stacked.counts, stacked.centroids, tree.leaf_members, pos, vac, pos,
-        jnp.zeros((64,), jnp.int32), jnp.arange(64, dtype=jnp.int32), live,
-        jnp.int32(1), jnp.int32(0), seed=cfg.seed, sizes=stacked.sizes,
-        theta=cfg.theta, sigma=cfg.sigma, frontier=cfg.frontier_cap,
-        n_levels=traverse.phase_b_levels(cfg))
+    restarts = traverse.phase_b_levels(cfg)
+    kw = dict(seed=cfg.seed, sizes=stacked.sizes, theta=cfg.theta,
+              sigma=cfg.sigma, frontier=cfg.frontier_cap, n_levels=restarts)
+    args = (pos, vac, pos, jnp.zeros((64,), jnp.int32),
+            jnp.arange(64, dtype=jnp.int32), live, jnp.int32(1),
+            jnp.int32(0))
+    if block is None:
+        _, _, depth, overflow = traverse.phase_b_core(
+            stacked.counts, stacked.centroids, tree.leaf_members, *args,
+            **kw)
+        rows_run = 64 * restarts
+    else:
+        _, _, depth, overflow, rows_run = traverse.phase_b_reference(
+            stacked, tree, *args, kw, block=block)
     m = tm.Recorder(n=64).traversal(tm.init_metrics(), depth, live, overflow,
-                                    traverse.phase_b_levels(cfg))
+                                    rows_run, restarts)
     counted = {k: float(m.counters[k][0]) for k in PHASE_B_KEYS}
     assert counted["bh_queries_live"] == 48
     assert counted["bh_query_slots"] == 64
     assert counted["bh_rounds_run"] == 64 * 3
     assert counted["bh_frontier_overflow"] == (48 if overflows else 0)
+    if block is not None:
+        assert counted["bh_rows_run"] % block == 0
+        assert float(m.counters["bh_restarts"][0]) \
+            <= counted["bh_rows_run"] < counted["bh_rounds_run"]
 
 
 def test_phase_b_slots_and_live_rows_per_chunk():
@@ -214,6 +243,11 @@ def test_phase_b_slots_and_live_rows_per_chunk():
     np.testing.assert_array_equal(ring["bh_rounds_run"],
                                   [r * r * cap
                                    * traverse.phase_b_levels(SMALL)] * 2)
+    # the reference computes whole blocks (one block of all R * cap rows
+    # at this size) and never a row past the cap
+    assert not (ring["bh_rows_run"] % (r * cap)).any()
+    assert (ring["bh_restarts"] <= ring["bh_rows_run"]).all()
+    assert (ring["bh_rows_run"] <= ring["bh_rounds_run"]).all()
     assert not ring["request_overflow"].any()
     np.testing.assert_array_equal(ring["bh_queries_live"],
                                   ring["formation_requests"])
